@@ -69,7 +69,7 @@ def run(fast: bool = False) -> str:
                           "mtuples_per_s": round(w * b / t_rs / 1e6, 2)}
 
     # pallas interpret-mode agreement (semantics checksum)
-    a = extract_parse(raw[:256], c, backend="pallas")
+    a = extract_parse(raw[:256], c, backend="pallas-interpret")
     r = extract_parse(raw[:256], c, backend="ref")
     out["pallas_interpret_max_err"] = float(jnp.max(jnp.abs(a - r)))
 

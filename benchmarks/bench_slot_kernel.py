@@ -44,6 +44,7 @@ import time
 import jax
 import numpy as np
 
+from repro.compile_cache import use_compile_cache
 from repro.core.engine import EngineConfig, SlotOLAEngine, _Collectives
 from repro.core.queries import (
     Linear,
@@ -254,4 +255,5 @@ def main() -> None:
 
 
 if __name__ == "__main__":
+    use_compile_cache()
     main()

@@ -28,6 +28,7 @@ import os
 
 import numpy as np
 
+from repro.compile_cache import use_compile_cache
 from repro.core.controller import EstimationController
 from repro.core.engine import EngineConfig, OLAEngine
 from repro.core.queries import GroupBy, Linear, Query, Range, TRUE
@@ -1027,4 +1028,5 @@ def main() -> None:
 
 
 if __name__ == "__main__":
+    use_compile_cache()
     main()

@@ -12,6 +12,8 @@ import argparse
 import sys
 import time
 
+from repro.compile_cache import use_compile_cache
+
 
 BENCHES = [
     ("convergence", "benchmarks.bench_convergence"),     # Fig. 7-10
@@ -53,4 +55,5 @@ def main() -> None:
 
 
 if __name__ == "__main__":
+    use_compile_cache()
     main()

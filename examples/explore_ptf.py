@@ -11,6 +11,7 @@ no load, no full scan, no wasted work on an uninteresting batch.
 
 import numpy as np
 
+from repro.compile_cache import use_compile_cache
 from repro.core import (
     Column, EngineConfig, EstimationController, Having, Query, Range, TRUE,
 )
@@ -56,4 +57,5 @@ def main():
 
 
 if __name__ == "__main__":
+    use_compile_cache()
     main()

@@ -13,6 +13,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
+from repro.compile_cache import use_compile_cache
 from repro.configs import get_config
 from repro.models import build_model
 from repro.ola_ml.eval_ola import ola_eval
@@ -55,4 +56,5 @@ def _per_example_loss(model, params, toks, cfg):
 
 
 if __name__ == "__main__":
+    use_compile_cache()
     main()

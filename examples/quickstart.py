@@ -9,6 +9,7 @@ the estimate converging against the exact answer.
 
 import numpy as np
 
+from repro.compile_cache import use_compile_cache
 from repro.core import EngineConfig, EstimationController, Linear, Query, Range
 from repro.data.generator import make_synthetic_zipf, store_dataset
 
@@ -43,4 +44,5 @@ def main():
 
 
 if __name__ == "__main__":
+    use_compile_cache()
     main()

@@ -13,6 +13,7 @@ import time
 
 import numpy as np
 
+from repro.compile_cache import use_compile_cache
 from repro.configs import get_config
 from repro.serve.engine import Request, ServeEngine
 
@@ -46,4 +47,5 @@ def main():
 
 
 if __name__ == "__main__":
+    use_compile_cache()
     main()

@@ -13,6 +13,7 @@ scan.
 
 import numpy as np
 
+from repro.compile_cache import use_compile_cache
 from repro.core.engine import EngineConfig
 from repro.core.queries import Having, Linear, Query, Range
 from repro.data.generator import make_synthetic_zipf, store_dataset
@@ -66,4 +67,5 @@ def main():
 
 
 if __name__ == "__main__":
+    use_compile_cache()
     main()

@@ -20,6 +20,7 @@ import json
 
 import numpy as np
 
+from repro.compile_cache import use_compile_cache
 from repro.core.engine import EngineConfig
 from repro.core.queries import Linear, Query, Range
 from repro.data.generator import make_synthetic_zipf, store_dataset
@@ -94,4 +95,5 @@ def main():
 
 
 if __name__ == "__main__":
+    use_compile_cache()
     main()

@@ -12,6 +12,7 @@ few hundred steps on CPU).
 import argparse
 import json
 
+from repro.compile_cache import use_compile_cache
 from repro.configs import get_config
 from repro.data.corpus import SyntheticCorpus
 from repro.train.trainer import Trainer, TrainerConfig
@@ -49,4 +50,5 @@ def main():
 
 
 if __name__ == "__main__":
+    use_compile_cache()
     main()

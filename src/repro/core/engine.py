@@ -84,6 +84,7 @@ from repro.core.queries import (
 )
 from repro.kernels import ops as kernel_ops
 from repro.kernels.ref import TALLY_BUCKETS, tally_hash
+from repro.kernels.slot_extract import GATHER_ROWS
 from repro.sampling.permutation import (
     chunk_seed,
     permutation_window_dyn,
@@ -117,11 +118,11 @@ class EngineConfig:
     cache_cap: int = 0           # per-chunk extracted-tuple cache rows (synopsis)
     # round EXTRACT implementation: "ref" keeps the decode_ref + evaluator
     # composition (supports arbitrary Custom queries); "pallas" routes the
-    # gather+parse+eval+reduce through the fused kernels/slot_extract.py
-    # kernel (linear+range plans only; interpret-mode fallback off-TPU);
-    # "pallas-interpret" forces the Pallas interpreter even on TPU (the
-    # benchmark's correctness-mode lane); "auto" picks pallas on TPU when the
-    # plan supports it and ref elsewhere.
+    # gather+parse+eval+reduce through the compiled kernels/slot_extract.py
+    # kernel (linear+range plans only; raises off-TPU); "pallas-interpret"
+    # runs that kernel under the Pallas interpreter on any platform (the CPU
+    # parity tests, the benchmark's correctness-mode lane); "auto" picks
+    # pallas on TPU when the plan supports it and ref elsewhere.
     extract_backend: str = "ref"
     # raw-data residency: "packed" keeps the whole store on device as one
     # (N, M_max, rec) tensor (fine for small stores); "stream" feeds each
@@ -332,12 +333,13 @@ class EngineProgram:
         # The fused kernel parses fixed-width ASCII, needs linear+range
         # plans, and accumulates in float32: an explicit
         # "pallas"/"pallas-interpret" outside that raises here (not
-        # mid-scan), while "auto" quietly keeps the ref path — binary decode
-        # is near-free anyway (those stores are IO-bound, not EXTRACT-bound),
+        # mid-scan), while "auto" keeps the ref path — binary decode is
+        # near-free anyway (those stores are IO-bound, not EXTRACT-bound),
         # Custom frozen queries have no coefficient form, and a non-f32
-        # stats dtype must not be silently degraded to f32 sums.  Explicit
-        # "pallas" off-TPU runs the kernel in interpret mode;
-        # "pallas-interpret" forces the interpreter even on TPU.
+        # stats dtype must not be silently degraded to f32 sums.  "pallas"
+        # is the compiled kernel and raises off-TPU; only "pallas-interpret"
+        # runs the interpreter.  What "auto" chose is kept in
+        # ``extract_backend``.
         kernel_ok = (getattr(codec, "name", "") == "ascii"
                      and jnp.dtype(config.stats_dtype) == jnp.float32)
         backend = config.extract_backend
@@ -355,8 +357,10 @@ class EngineProgram:
                 f"extract_backend={backend!r} requires the fixed-width ASCII "
                 "codec and float32 stats (the fused kernel parses ASCII "
                 "records and accumulates its sums in f32)")
-        self._ops_backend = None if backend == "ref" else backend
-        self.extract_pallas = self._ops_backend is not None
+        elif backend == "pallas":
+            kernel_ops.require_tpu(backend)
+        self.extract_backend = backend
+        self.extract_pallas = backend != "ref"
         if (self.group_cells and self.extract_pallas
                 and config.residency == "stream"):
             raise ValueError(
@@ -631,14 +635,14 @@ class EngineProgram:
                     return kernel_ops.slot_extract_stream(
                         data, idx, budgets, coeffs, p_lo, p_hi, isc, gate_v,
                         weights=wts, row_tile=cfg.slab_row_tile,
-                        backend=self._ops_backend, cache_cap=cap,
+                        backend=self.extract_backend, cache_cap=cap,
                         m_before=m_before)
 
                 def _stream_dec(budgets):
                     return kernel_ops.slot_eval_decoded(
                         dec, idx, budgets, coeffs, p_lo, p_hi, isc, gate_v,
                         weights=wts, row_tile=cfg.slab_row_tile,
-                        backend=self._ops_backend, cache_cap=cap,
+                        backend=self.extract_backend, cache_cap=cap,
                         m_before=m_before)
 
                 if decoded_mode == "all":
@@ -661,7 +665,7 @@ class EngineProgram:
                 stats4, cols, gstats4, tal_w = kernel_ops.slot_extract(
                     data, j, idx, b_eff, coeffs, p_lo, p_hi, isc, gate_v,
                     weights=wts,
-                    return_cols=cap > 0, backend=self._ops_backend,
+                    return_cols=cap > 0, backend=self.extract_backend,
                     gcol=slots.gcol, gval=slots.gval, gact=slots.gact,
                     salt=state.round.astype(jnp.uint32),
                     tally_buckets=self.tally_buckets)
@@ -675,7 +679,7 @@ class EngineProgram:
                 stats4, cols = kernel_ops.slot_extract(
                     data, j, idx, b_eff, coeffs, p_lo, p_hi, isc, gate_v,
                     weights=wts,
-                    return_cols=cap > 0, backend=self._ops_backend)
+                    return_cols=cap > 0, backend=self.extract_backend)
             sum_x = stats4[..., 1].astype(dtype).T               # (Q|S, W)
             sum_xx = stats4[..., 2].astype(dtype).T
             sum_p = stats4[..., 3].astype(dtype).T
@@ -1303,7 +1307,9 @@ class _ResidencyMixin:
                 adaptive=config.prefetch_adaptive,
                 decoded_cache_bytes=config.decoded_cache_bytes)
             return store.chunk_sizes
-        packed, sizes = store.packed_device_view()
+        # rows padded to the fused kernel's aligned gather tile, so the
+        # kernel never re-pads (copies) the resident store per round
+        packed, sizes = store.packed_device_view(row_multiple=GATHER_ROWS)
         self.packed = (jnp.asarray(packed) if packed_put is None
                        else packed_put(packed))
         return sizes

@@ -21,9 +21,8 @@ import time
 from typing import Optional, Sequence
 
 import jax
-import jax.numpy as jnp
 import numpy as np
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import AxisType, Mesh, NamedSharding, PartitionSpec as P
 
 from repro.core.engine import (
     EngineConfig,
@@ -36,24 +35,6 @@ from repro.core.engine import (
 )
 from repro.core.estimators import BiLevelStats
 from repro.core.queries import Query, SlotTable
-
-try:  # jax >= 0.6 exposes shard_map at the top level
-    _shard_map = jax.shard_map
-except AttributeError:  # pragma: no cover
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-
-def shard_map(f, **kw):
-    """Version shim: the replication-check kwarg was renamed
-    check_rep -> check_vma across jax releases."""
-    try:
-        return _shard_map(f, **kw)
-    except TypeError:
-        if "check_vma" in kw:
-            kw = dict(kw)
-            kw["check_rep"] = kw.pop("check_vma")
-            return _shard_map(f, **kw)
-        raise
 
 
 def engine_state_specs() -> EngineState:
@@ -92,7 +73,13 @@ class _SPMDEngineBase(_ResidencyMixin):
 
     def __init__(self, store, config: EngineConfig, mesh: Mesh):
         self.store = store
-        self.mesh = mesh
+        # The engine runs on an Auto-typed view of the caller's mesh: the
+        # serving host writes single rows of the sharded state eagerly
+        # (admission seeds, claim reorders, quarantine), and under the
+        # Explicit axes that jax.make_mesh gives by default such an indexed
+        # update outside jax.set_mesh raises.
+        mesh = self.mesh = Mesh(mesh.devices, mesh.axis_names,
+                                axis_types=(AxisType.Auto,) * mesh.devices.ndim)
         self.n_dev = mesh.shape["data"]
         assert config.num_workers % self.n_dev == 0, (
             f"num_workers={config.num_workers} must divide over "
@@ -135,10 +122,11 @@ class _SPMDEngineBase(_ResidencyMixin):
                          if decoded_mode != "none" else P("data"))
         else:
             data_spec = P()
-        sm = shard_map(step, mesh=self.mesh,
-                       in_specs=(specs, *extra_in_specs, data_spec, P("data")),
-                       out_specs=(specs, report_specs()),
-                       check_vma=False)
+        sm = jax.shard_map(step, mesh=self.mesh,
+                           in_specs=(specs, *extra_in_specs, data_spec,
+                                     P("data")),
+                           out_specs=(specs, report_specs()),
+                           check_vma=False)
         return jax.jit(sm, donate_argnums=(0,))
 
     def budget_ladder(self, b: float) -> int:

@@ -12,7 +12,9 @@ into the slot's tracked group cells.  Two properties make the fold sound:
   the in-bucket variance is zero.  Mixed buckets are simply skipped.
 * **Transience.**  The hash salt is the round number, so two values that
   collide this round almost surely separate next round — a heavy value is
-  only ever *delayed*, never permanently masked.
+  only ever *delayed*, never permanently masked.  Promotion therefore waits
+  for WARMUP_ROUNDS folds as well as for enough mass: one large round can
+  carry all the mass while its collisions hide a heavy value entirely.
 
 Everything here is plain numpy on tiny arrays; the sketch never touches the
 device.
@@ -25,6 +27,12 @@ import numpy as np
 # relative tolerance for the single-occupant moment test; tally moments are
 # f32 sums, so pure buckets land ~1e-7·count away from exact equality
 PURITY_RTOL = 1e-4
+# folds (distinct hash salts) a sketch must absorb before its ranking drives
+# promotion.  With 32k-tuple rounds of the paper's Zipf data (90 values), a
+# promotion after one round locked the 3rd and 4th heaviest values out of
+# the cells; after 4 it ranked them right.  A scan shorter than this many
+# rounds promotes nothing.
+WARMUP_ROUNDS = 4
 
 
 def pure_buckets(tal: np.ndarray, rtol: float = PURITY_RTOL,
@@ -64,9 +72,11 @@ class GroupSketch:
         self.capacity = int(capacity)
         self.counts: dict[float, float] = {}
         self.errors: dict[float, float] = {}
-        # total pure-bucket mass absorbed — promotion policies gate on it
-        # (a sketch that has seen too little is ranked by noise)
+        # total pure-bucket mass absorbed and tally rows folded — promotion
+        # policies gate on both (a sketch that has seen too little, or too
+        # few hash salts, is ranked by noise)
         self.mass = 0.0
+        self.rounds = 0
 
     def __len__(self) -> int:
         return len(self.counts)
@@ -89,6 +99,7 @@ class GroupSketch:
 
     def fold(self, tal: np.ndarray, rtol: float = PURITY_RTOL) -> None:
         """Fold one round's ``(3, H)`` tally row into the sketch."""
+        self.rounds += 1
         for value, count in pure_buckets(tal, rtol):
             self.offer(value, count)
 

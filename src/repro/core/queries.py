@@ -28,6 +28,7 @@ import dataclasses
 import warnings
 from typing import Callable, NamedTuple, Optional, Sequence
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.scipy.special import ndtri
@@ -46,7 +47,8 @@ class Linear:
 
     def __call__(self, cols: jnp.ndarray) -> jnp.ndarray:
         c = jnp.asarray(self.coeffs, dtype=cols.dtype)
-        return cols[..., : len(self.coeffs)] @ c
+        return jnp.matmul(cols[..., : len(self.coeffs)], c,
+                          precision=jax.lax.Precision.HIGHEST)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -552,7 +554,9 @@ def slot_evaluate(table: SlotTable, cols: jnp.ndarray,
     # comparisons for any finite value — no special-casing needed
     inb = (c >= table.lo.astype(dtype)) & (c < table.hi.astype(dtype))
     p = jnp.all(inb, axis=-1)                                   # (..., S)
-    lin = jnp.einsum("...c,sc->...s", cols, table.coeffs.astype(dtype))
+    # full f32 precision: XLA:TPU's default rounds matmul operands to bf16
+    lin = jnp.einsum("...c,sc->...s", cols, table.coeffs.astype(dtype),
+                     precision=jax.lax.Precision.HIGHEST)
     is_count = table.agg == AGG_COUNT
     expr = jnp.where(is_count, jnp.ones_like(lin), lin)
     pf = p.astype(dtype)

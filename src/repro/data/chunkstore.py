@@ -223,13 +223,16 @@ class ChunkStore:
         if self._chunks[j] is None:
             self._chunks[j] = self.chunk_bytes(j)
 
-    def packed_device_view(self) -> tuple[np.ndarray, np.ndarray]:
-        """Padded ``(N, M_max, record_bytes)`` uint8 + ``(N,)`` sizes.
+    def packed_device_view(self, row_multiple: int = 1
+                           ) -> tuple[np.ndarray, np.ndarray]:
+        """Padded ``(N, M_pad, record_bytes)`` uint8 + ``(N,)`` sizes, where
+        ``M_pad`` is ``M_max`` rounded up to ``row_multiple``.
 
         Padding rows are zero; the engine masks by ``M_j`` so they are never
         included in estimation.
         """
-        n, mx, rb = self.num_chunks, self.max_chunk_tuples, self.codec.record_bytes
+        n, rb = self.num_chunks, self.codec.record_bytes
+        mx = -(-self.max_chunk_tuples // row_multiple) * row_multiple
         out = np.zeros((n, mx, rb), np.uint8)
         for j in range(n):
             raw = self.chunk_bytes(j)

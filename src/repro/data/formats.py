@@ -81,8 +81,12 @@ class AsciiFixedFormat:
         ipow = jnp.asarray([10 ** (INT_DIGITS - 1 - d) for d in range(INT_DIGITS)],
                            jnp.float32)
         fpow = jnp.asarray([10.0 ** -(d + 1) for d in range(FRAC_DIGITS)], jnp.float32)
-        ival = jnp.einsum("tcd,d->tc", (f[..., 1:1 + INT_DIGITS] - zero).astype(jnp.float32), ipow)
-        fval = jnp.einsum("tcd,d->tc", (f[..., 2 + INT_DIGITS:] - zero).astype(jnp.float32), fpow)
+        idig = (f[..., 1:1 + INT_DIGITS] - zero).astype(jnp.float32)
+        fdig = (f[..., 2 + INT_DIGITS:] - zero).astype(jnp.float32)
+        # full f32 precision: XLA:TPU's default rounds matmul operands to bf16
+        hi = jax.lax.Precision.HIGHEST
+        ival = jnp.einsum("tcd,d->tc", idig, ipow, precision=hi)
+        fval = jnp.einsum("tcd,d->tc", fdig, fpow, precision=hi)
         sign = jnp.where(f[..., 0] == ord("-"), -1.0, 1.0).astype(jnp.float32)
         return sign * (ival + fval)
 

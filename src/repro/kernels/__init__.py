@@ -20,7 +20,8 @@ three access patterns the engine uses:
   both query planes.
 
 ``ref.py`` holds the pure-jnp oracles; ``ops.py`` the jitted wrappers that
-dispatch to Pallas on TPU and to the oracle (or ``interpret=True``) on CPU.
+dispatch to the compiled kernel (``"pallas"``, TPU only), the Pallas
+interpreter (``"pallas-interpret"``) or the oracle (``"ref"``).
 """
 
 from repro.kernels.ops import (

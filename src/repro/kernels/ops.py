@@ -2,14 +2,16 @@
 
 ``backend`` semantics:
 
-* ``"auto"``    — Pallas on TPU, pure-jnp oracle elsewhere (production default:
-                  the oracle compiles to decent XLA:CPU code, while
-                  ``interpret=True`` is a debugging interpreter).
-* ``"pallas"``  — force pallas_call; on CPU this sets ``interpret=True``
-                  (used by the correctness sweeps in tests/).
-* ``"pallas-interpret"`` — force the Pallas interpreter even on TPU (the
-                  benchmarks' correctness-mode lane).
-* ``"ref"``     — force the oracle.
+* ``"auto"``    — the compiled kernel on TPU, the pure-jnp oracle elsewhere
+                  (the oracle compiles to decent XLA:CPU code, while the
+                  Pallas interpreter is a debugging tool).
+* ``"pallas"``  — the compiled kernel.  Needs a TPU: anywhere else it
+                  raises rather than quietly running the interpreter (a
+                  libtpu that failed to start leaves JAX on the CPU).
+* ``"pallas-interpret"`` — the kernel under the Pallas interpreter, on any
+                  platform (the CPU parity tests and the benchmarks'
+                  correctness-mode lane).
+* ``"ref"``     — the oracle.
 """
 
 from __future__ import annotations
@@ -33,12 +35,22 @@ def _on_tpu() -> bool:
     return jax.default_backend() == "tpu"
 
 
+def require_tpu(backend: str) -> None:
+    """Raise unless JAX runs on a TPU: ``backend`` names the compiled kernel."""
+    if not _on_tpu():
+        raise RuntimeError(
+            f"backend {backend!r} runs the compiled Pallas kernel, which needs "
+            f"a TPU, but JAX's default backend is {jax.default_backend()!r}; "
+            "use 'pallas-interpret' for the interpreter or 'ref'")
+
+
 def _resolve(backend: str) -> tuple[bool, bool]:
     """-> (use_pallas, interpret)"""
     if backend == "auto":
         return (_on_tpu(), False)
     if backend == "pallas":
-        return (True, not _on_tpu())
+        require_tpu(backend)
+        return (True, False)
     if backend == "pallas-interpret":
         return (True, True)
     if backend == "ref":

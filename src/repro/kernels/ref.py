@@ -10,7 +10,11 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from repro.data.formats import FIELD_BYTES, FRAC_DIGITS, INT_DIGITS
+from repro.data.formats import AsciiFixedFormat
+
+# f32 contractions here run at full precision: XLA:TPU's default rounds f32
+# matmul operands to bf16, far outside the kernels' fp32 parity tolerance.
+HIGHEST = jax.lax.Precision.HIGHEST
 
 # Group-discovery tally table width (power of two; shared by the engine's
 # jnp path, the Pallas kernel, and the host-side sketch fold).
@@ -35,19 +39,9 @@ def tally_hash(vals: jnp.ndarray, salt: jnp.ndarray,
 
 
 def parse_ascii_ref(raw: jnp.ndarray, num_cols: int) -> jnp.ndarray:
-    """(T, rec_bytes) uint8 fixed-width ASCII -> (T, C) f32."""
-    t = raw.shape[0]
-    f = raw.reshape(t, num_cols, FIELD_BYTES).astype(jnp.int32)
-    zero = jnp.int32(ord("0"))
-    ipow = jnp.asarray([10.0 ** (INT_DIGITS - 1 - d) for d in range(INT_DIGITS)],
-                       jnp.float32)
-    fpow = jnp.asarray([10.0 ** -(d + 1) for d in range(FRAC_DIGITS)], jnp.float32)
-    ival = jnp.einsum("tcd,d->tc",
-                      (f[..., 1:1 + INT_DIGITS] - zero).astype(jnp.float32), ipow)
-    fval = jnp.einsum("tcd,d->tc",
-                      (f[..., 2 + INT_DIGITS:] - zero).astype(jnp.float32), fpow)
-    sign = jnp.where(f[..., 0] == ord("-"), -1.0, 1.0).astype(jnp.float32)
-    return sign * (ival + fval)
+    """(T, rec_bytes) uint8 fixed-width ASCII -> (T, C) f32: the codec's
+    ``decode_ref``."""
+    return AsciiFixedFormat(num_cols).decode_ref(raw)
 
 
 def eval_plan_ref(vals: jnp.ndarray, coeffs: jnp.ndarray, lo: jnp.ndarray,
@@ -61,7 +55,7 @@ def eval_plan_ref(vals: jnp.ndarray, coeffs: jnp.ndarray, lo: jnp.ndarray,
     lo_b = lo.reshape(qshape)
     hi_b = hi.reshape(qshape)
     pred = jnp.all((vals[None] >= lo_b) & (vals[None] < hi_b), axis=-1)  # (Q, ...)
-    expr = jnp.einsum("...c,qc->q...", vals, coeffs)
+    expr = jnp.einsum("...c,qc->q...", vals, coeffs, precision=HIGHEST)
     pf = pred.astype(vals.dtype)
     return expr * pf, pf
 
@@ -161,7 +155,8 @@ def _group_stats_from_cols(cols: jnp.ndarray, b_eff: jnp.ndarray, coeffs, lo,
     # live); ungrouped slots would otherwise tally their clipped column
     moments = jnp.stack([p, p * colv, p * colv * colv], axis=2)  # (S, W, 3, B)
     moments = moments * gactf[:, -1][:, None, None, None]
-    tal = jnp.einsum("swmb,swbh->wsmh", moments, oh)             # (W, S, 3, H)
+    tal = jnp.einsum("swmb,swbh->wsmh", moments, oh,
+                     precision=HIGHEST)                          # (W, S, 3, H)
     return gstats, tal
 
 

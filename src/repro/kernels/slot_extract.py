@@ -12,25 +12,29 @@ it for the synopsis extraction cache).
 
 Geometry (grid ``(W,)`` — one step per worker):
 
-* ``packed (N, M_max, rec)`` uint8 stays in HBM; the worker's chunk id is a
+* ``packed (N, M_pad, rec)`` uint8 stays in HBM; the worker's chunk id is a
   **scalar-prefetch** argument, so the BlockSpec index map selects block
-  ``(1, M_max, rec)`` — the worker's whole chunk — for the VMEM window.
-  This is the paper's in-memory chunk: M_max·rec bytes must fit VMEM
-  (~16 MiB/core), which holds for the tens-of-MB/chunk guidance once a chunk
-  is split across cores; beyond that, :func:`slot_extract_stream_pallas`
-  below streams the round's slab through VMEM in row tiles.
+  ``(1, M_pad, rec)`` — the worker's whole chunk — for the VMEM window.
+  This is the paper's in-memory chunk: M_pad·rec bytes (double-buffered)
+  must fit VMEM, 8 MiB at 16384 rows of 256 bytes; beyond that,
+  :func:`slot_extract_stream_pallas` below streams the round's slab through
+  VMEM in row tiles.  ``M_pad`` is the chunk row count padded to
+  GATHER_ROWS (the engine's resident view is built padded).
 * ``idx (W, B)`` int32 permutation-window rows and ``b_eff (W,)`` budgets are
-  scalar-prefetch too (SMEM): row indices drive the in-kernel gather loop —
-  B dynamic sublane slices chunk→scratch, the canonical Pallas gather.
+  scalar-prefetch too (SMEM).  The kernel walks the window ROW_BLOCK rows at
+  a time: gather the block's rows into an int32 VMEM scratch, parse, evaluate
+  and fold into the output.  Mosaic lowers a uint8 load only at a sublane
+  offset it can prove is a multiple of the (32, 128) int8 tile, so each row
+  is selected out of the aligned 32-row tile that holds it.
 * plan blocks ``coeffs/lo/hi (S, C)`` f32, ``is_count/gate (S,)`` f32 are
   whole-array VMEM blocks shared by every step.
 * out ``(1, S, 4)`` f32 per step; optional ``(1, B, C)`` decoded block.
 
 B is a power of two from the engine's t_eval ladder, so block shapes are
-stable across rounds and recompiles are bounded.  VMEM per step at
-B=4096, C=16: 2 MiB scratch (int32 bytes) + chunk block + small plan/out
-blocks — fine; the (S, B, C) predicate temp is fused by Mosaic and never
-hits HBM.
+stable across rounds and recompiles are bounded, and ROW_BLOCK divides it.
+VMEM per step at B=4096, C=16: the chunk block, a (256, 256) int32 scratch,
+O(S·256) temporaries and the small plan/out blocks (tests/test_tpu_compile.py
+compiles it for a v5e).
 """
 
 from __future__ import annotations
@@ -45,13 +49,68 @@ from jax.experimental.pallas import tpu as pltpu
 from repro.data.formats import FIELD_BYTES
 from repro.kernels.chunk_agg import _eval_plan_block
 from repro.kernels.extract_parse import _parse_block
-from repro.kernels.ref import TALLY_BUCKETS
+from repro.kernels.ref import HIGHEST, TALLY_BUCKETS
 
 # int32 twins of the uint32 hash constants in repro.kernels.ref.tally_hash —
 # two's-complement multiply/xor wrap to the same bits, so the in-kernel hash
 # stays bit-identical to the oracle without uint arithmetic.
 _HASH_SALT_MUL = -1640531535      # uint32 2654435761
 _HASH_MIX_MUL = -2048144777       # uint32 2246822519
+
+
+# Window rows are gathered, parsed and folded ROW_BLOCK at a time, so the
+# int32 byte scratch and every (S, ..., rows) temporary stay O(ROW_BLOCK)
+# whatever the budget rung.
+ROW_BLOCK = 256
+# Mosaic loads uint8 only at sublane offsets it can prove are multiples of
+# the (32, 128) int8 tile; the chunk block's rows are padded to this multiple.
+GATHER_ROWS = 32
+
+
+def _gather_rows(packed_ref, idx_ref, w, start, scratch):
+    """``scratch[i] <- packed[chunk, idx[w, start + i]]`` widened to int32.
+
+    Each row is selected out of the aligned GATHER_ROWS-row tile that holds
+    it: a single-row uint8 load at a data-dependent offset does not lower.
+    """
+    m_rows = packed_ref.shape[1]
+
+    def body(i, carry):
+        row = jnp.clip(idx_ref[w, start + i], 0, m_rows - 1)
+        base = pl.multiple_of(row // GATHER_ROWS * GATHER_ROWS, GATHER_ROWS)
+        tile = packed_ref[0, pl.ds(base, GATHER_ROWS), :].astype(jnp.int32)
+        hit = jax.lax.broadcasted_iota(jnp.int32, tile.shape, 0) == row - base
+        scratch[pl.ds(i, 1), :] = jnp.sum(jnp.where(hit, tile, 0), axis=0,
+                                          keepdims=True)
+        return carry
+
+    jax.lax.fori_loop(0, scratch.shape[0], body, 0)
+
+
+def _fold_block(vals, start, beff, coeffs_ref, lo_ref, hi_ref, isc_ref,
+                gate_ref, wts_ref):
+    """Slot eval of one row block at window positions ``start + [0, rows)``
+    -> masked ``x, p`` (S, rows), the 0/1 budget mask ``ok_s`` and
+    ``mask = ok_s · gate``."""
+    x, p = _eval_plan_block(vals, coeffs_ref[...],
+                            lo_ref[...], hi_ref[...])        # (S, rows)
+    # COUNT slots carry zero coefficients; their x is the indicator itself
+    x = jnp.where(isc_ref[...][:, None] > 0.0, p, x)
+    # per-slot budget: fairness weight w_s caps slot s at the first
+    # ceil(w_s·b_eff) window rows (w_s = 1 → the full b_eff, bit-identical
+    # to the unweighted round)
+    bs = jnp.minimum(jnp.ceil(wts_ref[...] * beff.astype(jnp.float32)
+                              ).astype(jnp.int32), beff)     # (S,)
+    pos = jax.lax.broadcasted_iota(jnp.int32, (1, vals.shape[0]), 1) + start
+    ok_s = (pos < bs[:, None]).astype(jnp.float32)           # (S, rows)
+    mask = ok_s * gate_ref[...][:, None]                     # (S, rows)
+    return x * mask, p * mask, ok_s, mask
+
+
+def _moments(ok_s, x, p):
+    """(S, rows) block -> (S, 4) partial ``(m, Σx, Σx², Σp)``."""
+    return jnp.stack([jnp.sum(ok_s, -1), jnp.sum(x, -1),
+                      jnp.sum(x * x, -1), jnp.sum(p, -1)], axis=-1)
 
 
 def _slot_extract_kernel(jw_ref, beff_ref, idx_ref, packed_ref, coeffs_ref,
@@ -62,38 +121,36 @@ def _slot_extract_kernel(jw_ref, beff_ref, idx_ref, packed_ref, coeffs_ref,
     else:
         (stats_ref, scratch), cols_ref = refs, None
     w = pl.program_id(0)
+    rows = scratch.shape[0]
+    stats_ref[...] = jnp.zeros_like(stats_ref)
 
-    # gather the worker's permutation-window rows chunk→scratch (VMEM)
-    def gather(i, carry):
-        row = idx_ref[w, i]
-        r = pl.load(packed_ref, (pl.ds(0, 1), pl.ds(row, 1), slice(None)))
-        pl.store(scratch, (pl.ds(i, 1), slice(None)),
-                 r.reshape(1, -1).astype(jnp.int32))
+    def block(k, carry):
+        start = pl.multiple_of(k * rows, rows)
+        _gather_rows(packed_ref, idx_ref, w, start, scratch)
+        vals = _parse_block(scratch[...], num_cols)          # (rows, C) f32
+        if cols_ref is not None:
+            cols_ref[0, pl.ds(start, rows), :] = vals
+        x, p, ok_s, _ = _fold_block(vals, start, beff_ref[w], coeffs_ref,
+                                    lo_ref, hi_ref, isc_ref, gate_ref,
+                                    wts_ref)
+        stats_ref[0] += _moments(ok_s, x, p)
         return carry
 
-    jax.lax.fori_loop(0, budget, gather, 0)
+    jax.lax.fori_loop(0, budget // rows, block, 0)
 
-    vals = _parse_block(scratch[...], num_cols)              # (B, C) f32
-    if cols_ref is not None:
-        cols_ref[0] = vals
-    x, p = _eval_plan_block(vals, coeffs_ref[...],
-                            lo_ref[...], hi_ref[...])        # (S, B)
-    # COUNT slots carry zero coefficients; their x is the indicator itself
-    x = jnp.where(isc_ref[...][:, None] > 0.0, p, x)
-    # per-slot budget: fairness weight w_s caps slot s at the first
-    # ceil(w_s·b_eff) window rows (w_s = 1 → the full b_eff, bit-identical
-    # to the unweighted round)
-    beff = beff_ref[w]
-    bs = jnp.minimum(jnp.ceil(wts_ref[...] * beff.astype(jnp.float32)
-                              ).astype(jnp.int32), beff)     # (S,)
-    ok_s = (jax.lax.iota(jnp.int32, budget)[None, :]
-            < bs[:, None]).astype(jnp.float32)               # (S, B)
-    mask = ok_s * gate_ref[...][:, None]                     # (S, B)
-    x = x * mask
-    p = p * mask
-    stats_ref[0] = jnp.stack([
-        jnp.sum(ok_s, -1),
-        jnp.sum(x, -1), jnp.sum(x * x, -1), jnp.sum(p, -1)], axis=-1)
+
+def _packed_block_spec(m_rows: int, rec: int):
+    """The worker's whole chunk, selected by the prefetched chunk id."""
+    return pl.BlockSpec((1, m_rows, rec),
+                        lambda i, jw_ref, *refs: (jw_ref[i], 0, 0))
+
+
+def _pad_rows(packed):
+    """Pad the chunk axis to a GATHER_ROWS multiple (no-op for the engine's
+    resident view, which is built padded)."""
+    m = packed.shape[1]
+    pad = -m % GATHER_ROWS
+    return jnp.pad(packed, ((0, 0), (0, pad), (0, 0))) if pad else packed
 
 
 @functools.partial(jax.jit, static_argnames=("num_cols", "return_cols",
@@ -111,7 +168,8 @@ def slot_extract_pallas(packed: jnp.ndarray, jw: jnp.ndarray,
     ``weights`` are the scheduler's per-slot fairness shares (1 = full
     budget, see ``repro.sched.fairness``).
     """
-    n, m_max, rec = packed.shape
+    packed = _pad_rows(packed)
+    n, m_rows, rec = packed.shape
     assert rec == num_cols * FIELD_BYTES, (rec, num_cols)
     w, b = idx.shape
     s = coeffs.shape[0]
@@ -125,9 +183,7 @@ def slot_extract_pallas(packed: jnp.ndarray, jw: jnp.ndarray,
         num_scalar_prefetch=3,   # jw, b_eff, idx
         grid=(w,),
         in_specs=[
-            # the worker's whole chunk, selected by the prefetched chunk id
-            pl.BlockSpec((1, m_max, rec),
-                         lambda i, jw_ref, *refs: (jw_ref[i], 0, 0)),
+            _packed_block_spec(m_rows, rec),
             pl.BlockSpec((s, num_cols), lambda i, *refs: (0, 0)),
             pl.BlockSpec((s, num_cols), lambda i, *refs: (0, 0)),
             pl.BlockSpec((s, num_cols), lambda i, *refs: (0, 0)),
@@ -136,7 +192,7 @@ def slot_extract_pallas(packed: jnp.ndarray, jw: jnp.ndarray,
             pl.BlockSpec((s,), lambda i, *refs: (0,)),
         ],
         out_specs=out_specs,
-        scratch_shapes=[pltpu.VMEM((b, rec), jnp.int32)],
+        scratch_shapes=[pltpu.VMEM((min(b, ROW_BLOCK), rec), jnp.int32)],
     )
     out = pl.pallas_call(
         functools.partial(_slot_extract_kernel, num_cols=num_cols,
@@ -176,75 +232,67 @@ def _slot_extract_grouped_kernel(jw_ref, beff_ref, idx_ref, salt_ref,
     else:
         (stats_ref, gstats_ref, tal_ref, scratch), cols_ref = refs, None
     w = pl.program_id(0)
-
-    def gather(i, carry):
-        row = idx_ref[w, i]
-        r = pl.load(packed_ref, (pl.ds(0, 1), pl.ds(row, 1), slice(None)))
-        pl.store(scratch, (pl.ds(i, 1), slice(None)),
-                 r.reshape(1, -1).astype(jnp.int32))
-        return carry
-
-    jax.lax.fori_loop(0, budget, gather, 0)
-
-    vals = _parse_block(scratch[...], num_cols)              # (B, C) f32
-    if cols_ref is not None:
-        cols_ref[0] = vals
-    x, p = _eval_plan_block(vals, coeffs_ref[...],
-                            lo_ref[...], hi_ref[...])        # (S, B)
-    x = jnp.where(isc_ref[...][:, None] > 0.0, p, x)
-    beff = beff_ref[w]
-    bs = jnp.minimum(jnp.ceil(wts_ref[...] * beff.astype(jnp.float32)
-                              ).astype(jnp.int32), beff)     # (S,)
-    ok_s = (jax.lax.iota(jnp.int32, budget)[None, :]
-            < bs[:, None]).astype(jnp.float32)               # (S, B)
-    mask = ok_s * gate_ref[...][:, None]                     # (S, B)
-    x = x * mask
-    p = p * mask
-    stats_ref[0] = jnp.stack([
-        jnp.sum(ok_s, -1),
-        jnp.sum(x, -1), jnp.sum(x * x, -1), jnp.sum(p, -1)], axis=-1)
-
-    # per-slot group-column values via exact one-hot contraction over C
-    colv = jax.lax.dot_general(goh_ref[...], vals,
-                               (((1,), (1,)), ((), ())),
-                               preferred_element_type=jnp.float32)  # (S, B)
-
+    rows = scratch.shape[0]
+    stats_ref[...] = jnp.zeros_like(stats_ref)
+    gstats_ref[...] = jnp.zeros_like(gstats_ref)
+    tal_ref[...] = jnp.zeros_like(tal_ref)
     gvals = gval_ref[...]                                    # (S, G)
     gacts = gact_ref[...]
     n_slots, g = gvals.shape
-    eq = (colv[:, None, :] == gvals[:, :, None]).astype(jnp.float32)
-    trk = eq * gacts[:, :, None]                             # (S, G, B)
-    # __other__ (cell G-1): complement of the tracked-cell sum — a row
-    # matches at most one tracked value, so this is an exact 0/1 indicator
-    tracked = trk * (jax.lax.broadcasted_iota(jnp.int32, (1, g, 1), 1)
-                     < g - 1).astype(jnp.float32)
-    other = ((1.0 - jnp.sum(tracked, axis=1))
-             * gacts[:, g - 1][:, None])                     # (S, B)
-    is_last = jax.lax.broadcasted_iota(jnp.int32, (1, g, 1), 1) == g - 1
-    ind = jnp.where(is_last, other[:, None, :], trk)         # (S, G, B)
-    gx = ind * x[:, None]
-    gp = ind * p[:, None]
-    gstats_ref[0] = jnp.stack([
-        jnp.sum(ind * mask[:, None], -1),
-        jnp.sum(gx, -1), jnp.sum(gx * gx, -1), jnp.sum(gp, -1)], axis=-1)
-
-    # salted discovery tallies: hash bits match ref.tally_hash exactly
-    # (int32 wraparound == uint32), low-bit mask recovers the logical shift
     lg = tally_buckets.bit_length() - 1
     salt = salt_ref[0]
-    u = jax.lax.bitcast_convert_type(colv, jnp.int32)        # (S, B)
-    h = (u ^ (salt * jnp.int32(_HASH_SALT_MUL))) * jnp.int32(_HASH_MIX_MUL)
-    h = jnp.right_shift(h, jnp.int32(32 - lg)) & jnp.int32(tally_buckets - 1)
-    hcol = jax.lax.broadcasted_iota(jnp.int32, (budget, tally_buckets), 1)
-    rows = []
-    for s_i in range(n_slots):
-        oh = (h[s_i][:, None] == hcol).astype(jnp.float32)   # (B, H)
-        # tallies only while the slot discovers groups (__other__ cell live)
-        pt = p[s_i] * gacts[s_i, g - 1]
-        mom = jnp.stack([pt, pt * colv[s_i],
-                         pt * colv[s_i] * colv[s_i]], axis=0)  # (3, B)
-        rows.append(jnp.dot(mom, oh, preferred_element_type=jnp.float32))
-    tal_ref[0] = jnp.stack(rows, axis=0)                     # (S, 3, H)
+
+    def block(k, carry):
+        start = pl.multiple_of(k * rows, rows)
+        _gather_rows(packed_ref, idx_ref, w, start, scratch)
+        vals = _parse_block(scratch[...], num_cols)          # (rows, C) f32
+        if cols_ref is not None:
+            cols_ref[0, pl.ds(start, rows), :] = vals
+        x, p, ok_s, mask = _fold_block(vals, start, beff_ref[w], coeffs_ref,
+                                       lo_ref, hi_ref, isc_ref, gate_ref,
+                                       wts_ref)
+        stats_ref[0] += _moments(ok_s, x, p)
+
+        # per-slot group-column values via exact one-hot contraction over C
+        colv = jax.lax.dot_general(goh_ref[...], vals,
+                                   (((1,), (1,)), ((), ())),
+                                   precision=HIGHEST,
+                                   preferred_element_type=jnp.float32)
+        eq = (colv[:, None, :] == gvals[:, :, None]).astype(jnp.float32)
+        trk = eq * gacts[:, :, None]                         # (S, G, rows)
+        # __other__ (cell G-1): complement of the tracked-cell sum — a row
+        # matches at most one tracked value, so this is an exact 0/1 indicator
+        tracked = trk * (jax.lax.broadcasted_iota(jnp.int32, (1, g, 1), 1)
+                         < g - 1).astype(jnp.float32)
+        other = ((1.0 - jnp.sum(tracked, axis=1))
+                 * gacts[:, g - 1][:, None])                 # (S, rows)
+        is_last = jax.lax.broadcasted_iota(jnp.int32, (1, g, 1), 1) == g - 1
+        ind = jnp.where(is_last, other[:, None, :], trk)     # (S, G, rows)
+        gstats_ref[0] += _moments(ind * mask[:, None], ind * x[:, None],
+                                  ind * p[:, None])
+
+        # salted discovery tallies: hash bits match ref.tally_hash exactly
+        # (int32 wraparound == uint32), low-bit mask recovers the logical
+        # shift
+        u = jax.lax.bitcast_convert_type(colv, jnp.int32)    # (S, rows)
+        h = ((u ^ (salt * jnp.int32(_HASH_SALT_MUL)))
+             * jnp.int32(_HASH_MIX_MUL))
+        h = (jnp.right_shift(h, jnp.int32(32 - lg))
+             & jnp.int32(tally_buckets - 1))
+        hcol = jax.lax.broadcasted_iota(jnp.int32, (rows, tally_buckets), 1)
+        tal = []
+        for s_i in range(n_slots):
+            oh = (h[s_i][:, None] == hcol).astype(jnp.float32)   # (rows, H)
+            # tallies only while the slot discovers groups (__other__ live)
+            pt = p[s_i] * gacts[s_i, g - 1]
+            mom = jnp.stack([pt, pt * colv[s_i],
+                             pt * colv[s_i] * colv[s_i]], axis=0)  # (3, rows)
+            tal.append(jnp.dot(mom, oh, precision=HIGHEST,
+                               preferred_element_type=jnp.float32))
+        tal_ref[0] += jnp.stack(tal, axis=0)                 # (S, 3, H)
+        return carry
+
+    jax.lax.fori_loop(0, budget // rows, block, 0)
 
 
 @functools.partial(jax.jit, static_argnames=("num_cols", "tally_buckets",
@@ -264,7 +312,8 @@ def slot_extract_grouped_pallas(packed: jnp.ndarray, jw: jnp.ndarray,
     number -> ``(stats (W, S, 4), cols|None, gstats (W, S, G, 4),
     tal (W, S, 3, H))``.  Must allclose ``ref.slot_extract_grouped_ref``.
     """
-    n, m_max, rec = packed.shape
+    packed = _pad_rows(packed)
+    n, m_rows, rec = packed.shape
     assert rec == num_cols * FIELD_BYTES, (rec, num_cols)
     w, b = idx.shape
     s = coeffs.shape[0]
@@ -290,8 +339,7 @@ def slot_extract_grouped_pallas(packed: jnp.ndarray, jw: jnp.ndarray,
         num_scalar_prefetch=4,   # jw, b_eff, idx, salt
         grid=(w,),
         in_specs=[
-            pl.BlockSpec((1, m_max, rec),
-                         lambda i, jw_ref, *refs: (jw_ref[i], 0, 0)),
+            _packed_block_spec(m_rows, rec),
             pl.BlockSpec((s, num_cols), lambda i, *refs: (0, 0)),
             pl.BlockSpec((s, num_cols), lambda i, *refs: (0, 0)),
             pl.BlockSpec((s, num_cols), lambda i, *refs: (0, 0)),
@@ -303,7 +351,7 @@ def slot_extract_grouped_pallas(packed: jnp.ndarray, jw: jnp.ndarray,
             pl.BlockSpec((s, g), lambda i, *refs: (0, 0)),
         ],
         out_specs=out_specs,
-        scratch_shapes=[pltpu.VMEM((b, rec), jnp.int32)],
+        scratch_shapes=[pltpu.VMEM((min(b, ROW_BLOCK), rec), jnp.int32)],
     )
     out = pl.pallas_call(
         functools.partial(_slot_extract_grouped_kernel, num_cols=num_cols,
@@ -390,7 +438,7 @@ def _slot_extract_stream_kernel(beff_ref, mb_ref, slab_ref, idx_ref,
     def fold(i, carry):
         acc, cacc = carry
         # idx_ref is (1, B//bt, bt): sub-block i on the sublane dim
-        sl = pl.load(idx_ref, (pl.ds(0, 1), pl.ds(i, 1), slice(None)))
+        sl = idx_ref[0, pl.ds(i, 1), :]
         k = jax.lax.broadcasted_iota(jnp.int32, (1, bt), 1) + i * bt
         valid_s = (k < bs[:, None]).astype(jnp.float32)       # (S, bt)
         mem = (sl.reshape(bt, 1) == row_ids).astype(jnp.float32)  # (bt, T)
@@ -403,9 +451,9 @@ def _slot_extract_stream_kernel(beff_ref, mb_ref, slab_ref, idx_ref,
             # into their cache rows — only O(cap·C) ever reaches HBM.
             in_win = (k < beff).astype(jnp.float32)               # (1, bt)
             sel = ((mb + k) == cap_ids).astype(jnp.float32) * in_win
-            wv = jnp.dot(mem, vals,
+            wv = jnp.dot(mem, vals, precision=HIGHEST,
                          preferred_element_type=jnp.float32)      # (bt, C)
-            cacc = cacc + jnp.dot(sel, wv,
+            cacc = cacc + jnp.dot(sel, wv, precision=HIGHEST,
                                   preferred_element_type=jnp.float32)
         return acc, cacc
 

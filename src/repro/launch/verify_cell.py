@@ -35,9 +35,6 @@ from repro.core.queries import Column, Having, Query, Range, TRUE
 from repro.data.formats import AsciiFixedFormat
 from repro.sampling.permutation import permutation_window_dyn, random_chunk_order
 
-# version-shimmed (check_rep -> check_vma rename handled there)
-from repro.core.engine_spmd import shard_map
-
 
 def production_verify_program(n_chunks: int = 4096, m_per_chunk: int = 65536,
                               num_cols: int = 6, workers: int = 256,
@@ -219,10 +216,10 @@ def build_verify_cell(mesh: Mesh, layout: str = "replicated",
         packed_spec = P("data")
         step = _sharded_round(program, n_dev, budget)
 
-    sm = shard_map(step, mesh=mesh,
-                   in_specs=(specs, packed_spec, P("data")),
-                   out_specs=(specs, report_specs()),
-                   check_vma=False)
+    sm = jax.shard_map(step, mesh=mesh,
+                       in_specs=(specs, packed_spec, P("data")),
+                       out_specs=(specs, report_specs()),
+                       check_vma=False)
 
     state_abs = jax.eval_shape(program.init_state)
     shardings = jax.tree.map(lambda s: NamedSharding(mesh, s), specs,

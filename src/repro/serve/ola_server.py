@@ -54,7 +54,7 @@ from repro.core.engine import (
     slot_stats_write,
     zero_group_cells,
 )
-from repro.core.groupby import GroupSketch, promote_values
+from repro.core.groupby import WARMUP_ROUNDS, GroupSketch, promote_values
 from repro.core.queries import (
     PLAN_CODES,
     GroupResult,
@@ -250,7 +250,8 @@ class ServerOptions:
     # must absorb before non-pinned values are promoted into tracked cells.
     # Promotion is grow-only, so promoting off a few noisy early rounds
     # would permanently lock true heavy hitters out of the cell set; the
-    # warmup lets the SpaceSaving ranking stabilize first.
+    # warmup lets the SpaceSaving ranking stabilize first (it also waits for
+    # groupby.WARMUP_ROUNDS folds).
     group_warmup_tuples: int = 1024
 
 
@@ -1454,7 +1455,8 @@ class OLAWorkloadServer:
                 g_tal = np.asarray(rep.g_tal)
             sketch = self._slot_sketch[s]
             sketch.fold(g_tal[s])
-            if sketch.mass < self._group_warmup:
+            if (sketch.mass < self._group_warmup
+                    or sketch.rounds < WARMUP_ROUNDS):
                 continue    # ranking not yet trustworthy (see ServerOptions)
             gb = wq.query.group_by
             tracked = self._slot_groups[s]

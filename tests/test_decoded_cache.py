@@ -352,9 +352,11 @@ def test_engine_stream_decoded_bit_exact_ref():
 def test_engine_stream_decoded_close_pallas():
     store_kw = dict(t=2048, chunks=12, seed=3)
     queries = _queries()
-    off = _run(_store(**store_kw), queries, _cfg(extract_backend="pallas"))
+    off = _run(_store(**store_kw), queries,
+               _cfg(extract_backend="pallas-interpret"))
     on = _run(_store(**store_kw), queries,
-              _cfg(extract_backend="pallas", decoded_cache_bytes=1 << 26))
+              _cfg(extract_backend="pallas-interpret",
+                   decoded_cache_bytes=1 << 26))
     for k in KEYS:
         np.testing.assert_allclose(np.asarray(off[k]), np.asarray(on[k]),
                                    rtol=1e-6, atol=1e-4, err_msg=k)

@@ -1,9 +1,10 @@
 """Parity of the fused Pallas extraction path against the reference path.
 
 ``EngineConfig.extract_backend="pallas"`` routes the round's EXTRACT stage
-(gather + parse + slot eval + partial stats) through the fused
-``kernels/slot_extract.py`` kernel — in interpret mode on CPU, which is what
-these tests (and the CI fast job) exercise.  The contract: the pallas engine
+(gather + parse + slot eval + partial stats) through the compiled fused
+``kernels/slot_extract.py`` kernel on a TPU; ``"pallas-interpret"`` runs the
+same kernel under the Pallas interpreter, which is what these tests (and the
+CI fast job) exercise on the CPU.  The contract: the pallas engine
 matches the ref engine's ``RoundReport`` and ``BiLevelStats`` to fp32
 tolerance, round for round, in both query planes — the only difference is
 float summation order inside the fused reductions.
@@ -92,7 +93,7 @@ def test_kernel_matches_ref_oracle():
     sr, cr = slot_extract(packed, jw, idx, b_eff, coeffs, lo, hi, is_count,
                           gate, return_cols=True, backend="ref")
     sp, cp = slot_extract(packed, jw, idx, b_eff, coeffs, lo, hi, is_count,
-                          gate, return_cols=True, backend="pallas")
+                          gate, return_cols=True, backend="pallas-interpret")
     np.testing.assert_allclose(np.asarray(sr), np.asarray(sp), rtol=2e-6)
     np.testing.assert_allclose(np.asarray(cr), np.asarray(cp), rtol=1e-6)
     # gated-off slots contribute exactly nothing
@@ -104,7 +105,7 @@ def test_frozen_mode_parity():
     extraction cache (fed by the kernel's decoded-slab output)."""
     store = _store()
     engines = {be: OLAEngine(store, QUERIES, _cfg(extract_backend=be))
-               for be in ("ref", "pallas")}
+               for be in ("ref", "pallas-interpret")}
     states = {be: e.init_state() for be, e in engines.items()}
     for _ in range(12):
         reps = {}
@@ -112,12 +113,13 @@ def test_frozen_mode_parity():
             b = e.budget_ladder(float(states[be].budget))
             states[be], reps[be] = e.round_fn(b)(states[be], e.packed,
                                                  e.speeds)
-        _assert_report_close(reps["ref"], reps["pallas"])
-    _assert_stats_close(states["ref"].stats, states["pallas"].stats)
+        _assert_report_close(reps["ref"], reps["pallas-interpret"])
+    _assert_stats_close(states["ref"].stats, states["pallas-interpret"].stats)
+    pal = states["pallas-interpret"]
     np.testing.assert_allclose(np.asarray(states["ref"].cache),
-                               np.asarray(states["pallas"].cache), rtol=1e-6)
+                               np.asarray(pal.cache), rtol=1e-6)
     np.testing.assert_array_equal(np.asarray(states["ref"].scan_m),
-                                  np.asarray(states["pallas"].scan_m))
+                                  np.asarray(pal.scan_m))
 
 
 def test_cmp_predicates_agree_across_backends():
@@ -135,29 +137,29 @@ def test_cmp_predicates_agree_across_backends():
                 name="band"),
           Query(agg="count", pred=Cmp(0, "==", 3.0), name="eq")]
     finals = {}
-    for be in ("ref", "pallas"):
+    for be in ("ref", "pallas-interpret"):
         eng = OLAEngine(store, qs, _cfg(extract_backend=be, cache_cap=0,
                                         strategy="holistic"))
         state, _ = eng.run(max_rounds=50)
         finals[be] = np.asarray(state.stats.psum).sum(axis=1)
-    np.testing.assert_array_equal(finals["ref"], finals["pallas"])
+    np.testing.assert_array_equal(finals["ref"], finals["pallas-interpret"])
     assert finals["ref"][0] == 128  # <= includes the threshold value
     assert finals["ref"][1] == 128  # > excludes it
     with pytest.raises(ValueError, match="not range-encodable"):
         OLAEngine(store, [Query(agg="count", pred=Cmp(0, "!=", 2.0))],
-                  _cfg(extract_backend="pallas"))
+                  _cfg(extract_backend="pallas-interpret"))
 
 
 def test_frozen_mode_pallas_rejects_nonlinear():
     store = _store(t=512, chunks=4)
     q = Query(agg="sum", expr=SquaredDiff(0, 1), epsilon=0.05)
     with pytest.raises(ValueError, match="not linear"):
-        OLAEngine(store, [q], _cfg(extract_backend="pallas"))
+        OLAEngine(store, [q], _cfg(extract_backend="pallas-interpret"))
     OLAEngine(store, [q], _cfg(extract_backend="ref"))  # ref path still fine
     # the kernel accumulates in f32: a non-f32 stats dtype must fail loud on
     # the explicit backend (and 'auto' silently resolves to ref instead)
     with pytest.raises(ValueError, match="float32 stats"):
-        OLAEngine(store, QUERIES[:1], _cfg(extract_backend="pallas",
+        OLAEngine(store, QUERIES[:1], _cfg(extract_backend="pallas-interpret",
                                            stats_dtype="bfloat16"))
     eng = OLAEngine(store, QUERIES[:1], _cfg(extract_backend="auto",
                                              stats_dtype="bfloat16"))
@@ -169,7 +171,7 @@ def test_slot_mode_parity_with_midscan_admission():
     mid-scan (round 4) and one retired early (round 8)."""
     store = _store()
     engines = {be: SlotOLAEngine(store, 4, _cfg(extract_backend=be))
-               for be in ("ref", "pallas")}
+               for be in ("ref", "pallas-interpret")}
     states = {be: e.init_state() for be, e in engines.items()}
     table = empty_slot_table(4, 8)
     table = slot_table_set(table, 0, encode_slot(QUERIES[0], 8,
@@ -187,8 +189,8 @@ def test_slot_mode_parity_with_midscan_admission():
             b = e.budget_ladder(float(states[be].budget))
             states[be], reps[be] = e.round_fn(b)(states[be], table, e.packed,
                                                  e.speeds)
-        _assert_report_close(reps["ref"], reps["pallas"])
-    _assert_stats_close(states["ref"].stats, states["pallas"].stats)
+        _assert_report_close(reps["ref"], reps["pallas-interpret"])
+    _assert_stats_close(states["ref"].stats, states["pallas-interpret"].stats)
 
 
 def test_workload_server_on_pallas_backend():
@@ -196,7 +198,7 @@ def test_workload_server_on_pallas_backend():
     kernel-fed cache, retirement) answers the same queries on both backends."""
     store = _store()
     results = {}
-    for be in ("ref", "pallas"):
+    for be in ("ref", "pallas-interpret"):
         srv = OLAWorkloadServer(
                   store, _cfg(extract_backend=be),
                   options=ServerOptions(max_slots=4,
@@ -206,8 +208,9 @@ def test_workload_server_on_pallas_backend():
         res = srv.run(max_rounds=4000)
         assert not srv.truncated
         results[be] = res
-    assert [r.qid for r in results["ref"]] == [r.qid for r in results["pallas"]]
-    for ra, rb in zip(results["ref"], results["pallas"]):
+    assert ([r.qid for r in results["ref"]]
+            == [r.qid for r in results["pallas-interpret"]])
+    for ra, rb in zip(results["ref"], results["pallas-interpret"]):
         assert ra.tuples_seen == rb.tuples_seen, (ra, rb)
         np.testing.assert_allclose(ra.estimate, rb.estimate, rtol=2e-5)
         np.testing.assert_allclose(ra.err, rb.err, rtol=1e-3, atol=1e-6)
@@ -218,8 +221,9 @@ def test_auto_backend_resolves_off_tpu():
     CPU deployments — and the engine still runs."""
     store = _store(t=512, chunks=4)
     eng = OLAEngine(store, QUERIES[:1], _cfg(extract_backend="auto"))
-    assert eng.program.extract_pallas == (
-        __import__("jax").default_backend() == "tpu")
+    on_tpu = __import__("jax").default_backend() == "tpu"
+    assert eng.program.extract_pallas == on_tpu
+    assert eng.program.extract_backend == ("pallas" if on_tpu else "ref")
     state, hist = eng.run(max_rounds=3)
     assert len(hist) >= 1
     # 'auto' must degrade to ref (not raise) for non-linear frozen queries
@@ -237,6 +241,25 @@ def test_pallas_interpret_backend_forced():
     eng = OLAEngine(store, QUERIES[:1], _cfg(
         extract_backend="pallas-interpret"))
     assert eng.program.extract_pallas
-    assert eng.program._ops_backend == "pallas-interpret"
+    assert eng.program.extract_backend == "pallas-interpret"
     state, hist = eng.run(max_rounds=3)
     assert len(hist) >= 1
+
+
+def test_pallas_backend_requires_tpu():
+    """'pallas' is the compiled kernel: off-TPU it raises, at the kernel
+    entry point and at engine build, instead of running the interpreter."""
+    if __import__("jax").default_backend() == "tpu":
+        pytest.skip("the compiled kernel runs here")
+    store = _store(t=512, chunks=4)
+    with pytest.raises(RuntimeError, match="needs a TPU"):
+        OLAEngine(store, QUERIES[:1], _cfg(extract_backend="pallas"))
+    with pytest.raises(RuntimeError, match="needs a TPU"):
+        SlotOLAEngine(store, 2, _cfg(extract_backend="pallas"))
+    packed, _ = store.packed_device_view()
+    z = np.zeros((1, 8), np.float32)
+    with pytest.raises(RuntimeError, match="pallas-interpret"):
+        slot_extract(jnp.asarray(packed), np.zeros(2, np.int32),
+                     np.zeros((2, 8), np.int32), np.zeros(2, np.int32),
+                     z, z - np.inf, z + np.inf, np.zeros(1), np.ones(1),
+                     backend="pallas")

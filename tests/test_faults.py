@@ -345,7 +345,8 @@ def test_reader_thread_stashes_failures_and_close_joins():
 # Zero-fault wrapper parity: ref/pallas × packed/stream + scheduled server
 # ---------------------------------------------------------------------------
 
-@pytest.mark.parametrize("backend", ["ref", "pallas"])
+@pytest.mark.parametrize("backend", [
+    "ref", pytest.param("pallas-interpret", id="pallas")])
 @pytest.mark.parametrize("residency", ["packed", "stream"])
 def test_zero_fault_wrapper_engine_parity(backend, residency):
     vals = _vals(t=384, seed=3)

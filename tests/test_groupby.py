@@ -148,7 +148,7 @@ def test_grouped_vs_fanout_bit_exact():
 def test_grouped_stream_pallas_rejected():
     store = _wiki_store(256, 2)
     cfg = EngineConfig(num_workers=2, max_groups=2, residency="stream",
-                       extract_backend="pallas")
+                       extract_backend="pallas-interpret")
     with pytest.raises(ValueError, match="packed"):
         SlotOLAEngine(store, 1, cfg)
 
@@ -187,17 +187,17 @@ def test_grouped_kernel_matches_ref_oracle():
     gact[2, -1] = 1.0
 
     outs = {}
-    for be in ("ref", "pallas"):
+    for be in ("ref", "pallas-interpret"):
         st, _, gs, tal = slot_extract(
             packed, jw, idx, b_eff, coeffs, lo, hi, is_count, gate,
             backend=be, gcol=gcol, gval=gval, gact=gact, salt=7)
         outs[be] = (np.asarray(st), np.asarray(gs), np.asarray(tal))
-    np.testing.assert_allclose(outs["ref"][0], outs["pallas"][0],
+    np.testing.assert_allclose(outs["ref"][0], outs["pallas-interpret"][0],
                                rtol=2e-5, atol=1e-2)
-    np.testing.assert_allclose(outs["ref"][1], outs["pallas"][1],
+    np.testing.assert_allclose(outs["ref"][1], outs["pallas-interpret"][1],
                                rtol=2e-5, atol=1e-2)
     # tallies are integer-weighted moment sums of identical products
-    np.testing.assert_array_equal(outs["ref"][2], outs["pallas"][2])
+    np.testing.assert_array_equal(outs["ref"][2], outs["pallas-interpret"][2])
     # ungrouped slot contributes no cells or tallies
     assert np.all(outs["ref"][1][:, 1] == 0.0)
     assert np.all(outs["ref"][2][:, 1] == 0.0)
@@ -275,6 +275,34 @@ def test_server_discovery_topk_recall_zipf():
         if gres.value in per_lang and per_lang[gres.value] > 0:
             assert abs(gres.estimate - per_lang[gres.value]) <= max(
                 0.15 * per_lang[gres.value], 1e3), gres
+
+
+def test_discovery_with_large_rounds_finds_exact_top_k():
+    """One round can carry the whole warmup mass while that round's salted
+    collisions hide a heavy value; promotion waits for several folds, so
+    large rounds still track the exact top-K (the paper's Zipf column 15:
+    the 3rd and 4th values hold 1.5% and 0.5% of the rows)."""
+    from repro.data.generator import make_synthetic_zipf
+
+    vals = make_synthetic_zipf(1 << 18, 16, seed=0)
+    store = store_dataset(vals, 16, "ascii")
+    cfg = EngineConfig(num_workers=8, seed=0, budget_init=4096,
+                       budget_max=4096, max_groups=8)
+    srv = OLAWorkloadServer(store, cfg, options=ServerOptions(
+        max_slots=1, synopsis_budget_tuples=0))
+    q = Query(agg="count", epsilon=0.05,
+              group_by=GroupBy(col=15, max_groups=8, top_k=4))
+    srv.submit(q, arrival_t=0.0, plan="single_pass")
+    srv.step()
+    assert srv._slot_sketch[0].mass >= 1024      # the mass gate alone opens
+    assert srv._slot_groups[0] == []             # ... the rounds gate holds
+    res = srv.run(max_rounds=4000)
+    assert len(res) == 1
+    keys, counts = np.unique(np.float32(vals[:, 15]), return_counts=True)
+    top = set(keys[np.argsort(-counts)[:4]].tolist())
+    tracked = {float(np.float32(g.value)) for g in res[0].groups
+               if not g.is_other}
+    assert top <= tracked, (sorted(top), sorted(tracked))
 
 
 def test_grouped_requires_group_capacity():

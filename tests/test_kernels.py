@@ -27,7 +27,7 @@ def test_extract_parse_sweep(t, c):
     fmt = AsciiFixedFormat(c)
     vals = rng.uniform(-1e6, 1e6, (t, c))
     raw = jnp.asarray(fmt.encode(vals))
-    a = np.asarray(extract_parse(raw, c, backend="pallas"))
+    a = np.asarray(extract_parse(raw, c, backend="pallas-interpret"))
     b = np.asarray(extract_parse(raw, c, backend="ref"))
     np.testing.assert_allclose(a, b, rtol=1e-6, atol=1e-5)
     np.testing.assert_allclose(a, vals, rtol=1e-5, atol=1e-2)
@@ -43,7 +43,7 @@ def test_chunk_agg_sweep(n, m, c):
     sizes = rng.integers(1, m + 1, n).astype(np.int32)
     plan = _plan(c)
     a = np.asarray(chunk_agg(jnp.asarray(raw), sizes, plan.coeffs, plan.lo,
-                             plan.hi, backend="pallas"))
+                             plan.hi, backend="pallas-interpret"))
     b = np.asarray(chunk_agg(jnp.asarray(raw), sizes, plan.coeffs, plan.lo,
                              plan.hi, backend="ref"))
     np.testing.assert_allclose(a, b, rtol=RTOL, atol=1e-2)
@@ -61,7 +61,7 @@ def test_round_stats_sweep(w, b):
     beff = rng.integers(0, b + 1, w).astype(np.int32)
     plan = _plan(c)
     a = np.asarray(round_stats(jnp.asarray(slab), beff, plan.coeffs, plan.lo,
-                               plan.hi, backend="pallas"))
+                               plan.hi, backend="pallas-interpret"))
     rr = np.asarray(round_stats(jnp.asarray(slab), beff, plan.coeffs, plan.lo,
                                 plan.hi, backend="ref"))
     np.testing.assert_allclose(a, rr, rtol=RTOL, atol=1e-2)
@@ -78,7 +78,7 @@ def test_chunk_agg_matches_brute_force():
     sizes = np.asarray([m, 77, 5], np.int32)
     plan = _plan(c, nq=1)
     out = np.asarray(chunk_agg(jnp.asarray(raw), sizes, plan.coeffs, plan.lo,
-                               plan.hi, backend="pallas"))
+                               plan.hi, backend="pallas-interpret"))
     for j in range(n):
         d = data[j][: sizes[j]]
         sel = (d[:, 0] >= -500) & (d[:, 0] < 500)

@@ -220,7 +220,8 @@ def test_stream_kernel_matches_ref_oracle():
     args = (idx, b_eff, coeffs, lo, hi, is_count, gate)
 
     sr = np.asarray(slot_extract_stream(slab, *args, backend="ref"))
-    sp = np.asarray(slot_extract_stream(slab, *args, backend="pallas"))
+    sp = np.asarray(slot_extract_stream(slab, *args,
+                                        backend="pallas-interpret"))
     np.testing.assert_allclose(sr, sp, rtol=1e-5, atol=1e-3)
     assert np.all(sp[:, 2, 1:] == 0.0)          # gated-off slot contributes 0
     assert np.all(sp[:, :, 0] == b_eff[:, None])    # m column is b_eff
@@ -230,17 +231,17 @@ def test_stream_kernel_matches_ref_oracle():
     sr = np.asarray(slot_extract_stream(slab, idx_dup, *args[1:],
                                         backend="ref"))
     sp = np.asarray(slot_extract_stream(slab, idx_dup, *args[1:],
-                                        backend="pallas"))
+                                        backend="pallas-interpret"))
     np.testing.assert_allclose(sr, sp, rtol=1e-5, atol=1e-3)
 
 
 def test_stream_engine_pallas_matches_ref():
-    """residency="stream" × extract_backend="pallas": the row-tiled kernel
-    drives the full engine round to fp32 tolerance against the ref path,
+    """residency="stream" × the Pallas kernel (interpreted): the row-tiled
+    kernel drives the full engine round to fp32 tolerance against the ref path,
     including the separately-decoded synopsis cache."""
     store = _store(t=1024, chunks=8)
     states, reps = {}, {}
-    for be in ("ref", "pallas"):
+    for be in ("ref", "pallas-interpret"):
         eng = OLAEngine(store, QUERIES, _cfg(
             residency="stream", extract_backend=be,
             budget_min=32, budget_max=32))
@@ -251,17 +252,18 @@ def test_stream_engine_pallas_matches_ref():
         states[be], reps[be] = s, r
         eng.close()
     np.testing.assert_allclose(np.asarray(reps["ref"].estimate),
-                               np.asarray(reps["pallas"].estimate),
+                               np.asarray(reps["pallas-interpret"].estimate),
                                rtol=2e-5, atol=1e-6)
     for name in ("ysum", "ysq", "psum"):
         np.testing.assert_allclose(
             np.asarray(getattr(states["ref"].stats, name)),
-            np.asarray(getattr(states["pallas"].stats, name)),
+            np.asarray(getattr(states["pallas-interpret"].stats, name)),
             rtol=2e-5, atol=1e-3)
+    pal = states["pallas-interpret"]
     np.testing.assert_allclose(np.asarray(states["ref"].cache),
-                               np.asarray(states["pallas"].cache), rtol=1e-6)
+                               np.asarray(pal.cache), rtol=1e-6)
     np.testing.assert_array_equal(np.asarray(states["ref"].scan_m),
-                                  np.asarray(states["pallas"].scan_m))
+                                  np.asarray(pal.scan_m))
 
 
 # ---------------------------------------------------------------------------
