@@ -73,7 +73,7 @@ def run_server(store, cfg, arrivals, max_slots, scheduler=None):
 
     results = srv.run(on_round=_sample)
     assert not srv.truncated, "workload did not finish; stats would be biased"
-    lat = np.asarray([r.latency for r in results])
+    lat = np.asarray([r.latency_model_s for r in results])
     out = {
         "tuples": srv.tuples_scanned,
         "lat_mean": float(lat.mean()),
@@ -278,7 +278,7 @@ def run_rollup_lane(store, cfg, slots: int, smoke: bool = False) -> dict:
     base_srv, _ = _serve(None)
     srv, results = _serve(RollupConfig(promote_hits=2))
     tier1 = [r for r in results if r.sched_outcome == "tier1"]
-    t1_lat = np.asarray([r.latency for r in tier1], float)
+    t1_lat = np.asarray([r.latency_model_s for r in tier1], float)
     out = {
         "num_queries": len(queries),
         "hot_queries": hot_count,
@@ -816,8 +816,9 @@ def _same_float(a, b) -> bool:
 def _answer_key(results) -> list:
     """The answer-affecting fields of a result list — anything tracing
     could conceivably perturb if it ever leaked into the arithmetic."""
-    return [(r.qid, repr(r.estimate), repr(r.halfwidth), repr(r.latency),
-             r.sched_outcome, r.rounds_resident, r.from_synopsis)
+    return [(r.qid, repr(r.estimate), repr(r.halfwidth),
+             repr(r.latency_model_s), r.sched_outcome, r.rounds_resident,
+             r.from_synopsis)
             for r in results]
 
 
@@ -957,7 +958,7 @@ def _run_groups_only(smoke: bool = True) -> str:
         recalls.append(len(true_top & tracked) / len(true_top))
         spill_seen += any(g.is_other and g.n > 0 for g in r.groups)
     recall = float(np.mean(recalls))
-    lat = np.asarray([r.latency for r in results])
+    lat = np.asarray([r.latency_model_s for r in results])
     assert recall >= 0.9, (recall, recalls)
 
     groups_out = {

@@ -83,14 +83,14 @@ def latency_stats(results) -> dict:
     (``slo_met is not None``); it is ``None`` when none did.  Outcome counts
     split scan-served answers from queued/shed ones.
     """
-    lat = np.asarray([r.latency for r in results], float)
+    lat = np.asarray([r.latency_model_s for r in results], float)
     out = {
         "p50_latency_s": float(np.percentile(lat, 50)) if len(lat) else None,
         "p95_latency_s": float(np.percentile(lat, 95)) if len(lat) else None,
         "p99_latency_s": float(np.percentile(lat, 99)) if len(lat) else None,
         "mean_latency_s": float(lat.mean()) if len(lat) else None,
-        "mean_queue_wait_s": float(np.mean([r.queue_wait for r in results]))
-        if results else None,
+        "mean_queue_wait_s": float(np.mean(
+            [r.queue_wait_model_s for r in results])) if results else None,
         "outcomes": {
             k: sum(r.sched_outcome == k for r in results)
             for k in ("admitted", "queued", "preempted", "shed", "tier1")},

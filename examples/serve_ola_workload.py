@@ -55,10 +55,11 @@ def main():
     results = server.run()
 
     print(f"\n{'query':>14} {'plan':>14} {'estimate':>12} {'err%':>6} "
-          f"{'dec':>3} {'latency(s)':>10} {'seeded':>6} {'seen':>6}")
+          f"{'dec':>3} {'model s':>10} {'seeded':>6} {'seen':>6}")
     for r in results:
         print(f"{r.name:>14} {r.plan:>14} {r.estimate:12.4g} "
-              f"{100 * r.err:6.2f} {r.decision:3d} {r.latency:10.5f} "
+              f"{100 * r.err:6.2f} {r.decision:3d} "
+              f"{r.latency_model_s:10.5f} "
               f"{r.seeded_tuples:6d} {r.tuples_seen:6d}")
     print(f"\nshared scan extracted {server.tuples_scanned} of "
           f"{store.num_tuples} tuples for {len(results)} queries "

@@ -7,9 +7,10 @@ attached, then:
 
 * saves the query-lifecycle trace as chrome-trace JSON
   (``ola_trace.json`` — open it at https://ui.perfetto.dev or in
-  ``chrome://tracing``): one ``round`` span per server round, with
-  ``claims``/``kernel``/``merge``/``estimate`` children and the
-  reader-thread ``READ`` spans on their own track;
+  ``chrome://tracing``): ``ola.submit`` and ``ola.admit`` spans, and one
+  ``ola.round`` span per server round with ``ola.claims``/
+  ``ola.dispatch``/``ola.device_wait``/``ola.merge``/``ola.retire``
+  children (the same spans go to a JAX profiler trace when one records);
 * prints each query's explain record — the admission decision with its
   Eq. (4) cost terms, the tier that answered, and the per-round
   ``(m, estimate, ci_halfwidth)`` convergence trajectory;

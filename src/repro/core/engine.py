@@ -1281,8 +1281,8 @@ class _ResidencyMixin:
     pipeline = None
     #: Span tracer for the host-side round feed (claims prediction + slab
     #: assembly).  Default is the shared no-op; :meth:`set_tracer` swaps in
-    #: a live one and propagates it to the prefetcher so READ spans land in
-    #: the same trace under the reader thread's tid.
+    #: the server's and propagates it to the prefetcher so ``ola.read``
+    #: spans land in the same trace on the reader thread.
     tracer = NULL_TRACER
 
     def set_tracer(self, tracer) -> None:
@@ -1317,7 +1317,7 @@ class _ResidencyMixin:
     def round_data(self, state: EngineState) -> tuple[EngineState, object]:
         if self.pipeline is None:
             return state, self.packed
-        with self.tracer.span("assemble"):
+        with self.tracer.span("ola.assemble"):
             while True:
                 j, active, new_head = self.program.plan_claims(state)
                 qn = np.asarray(state.quarantined)

@@ -271,7 +271,7 @@ class SlabPrefetcher:
             self._dec_ring = None
         self._empty_slab_dev = None  # lazy (W, 0, rec) raw leaf, all-dec rounds
         self._last_assembled: dict[int, int] = {}
-        # span tracer (host-side; NULL_TRACER = one method call when off)
+        # span tracer (host-side; the engine's set_tracer replaces it)
         self.tracer = NULL_TRACER
         # counters (monitoring / tests) — cumulative for the prefetcher's
         # lifetime; see COUNTER_FIELDS for the lifecycle contract.  The
@@ -323,7 +323,7 @@ class SlabPrefetcher:
                         verify(j, raw)
                     return raw
 
-                with self.tracer.span("READ", chunk=j):
+                with self.tracer.span("ola.read", chunk=j):
                     raw, retries = self.retry.call(_verified_read, j)
                 self.store.evict(j)  # host residency stays O(slab)
                 dt = time.perf_counter() - t0
@@ -353,12 +353,10 @@ class SlabPrefetcher:
         the device computes the current round (READ/compute overlap)."""
         if self._closed:
             return
-        n = 0
-        for j in chunk_ids:
-            self._hints.put(int(j))
-            n += 1
-        if n and self.tracer.enabled:
-            self.tracer.event("prefetch_hint", n=n)
+        ids = [int(j) for j in chunk_ids]
+        with self.tracer.span("ola.prefetch", chunks=len(ids)):
+            for j in ids:
+                self._hints.put(j)
 
     def _fill_raw(self, j: int, out_rows: np.ndarray) -> np.ndarray:
         """Fill ``out_rows[:rows]`` with chunk ``j``'s bytes in place.
@@ -376,7 +374,7 @@ class SlabPrefetcher:
             inflight = j in self._inflight
         if raw is None and not inflight and self._direct_readinto:
             t0 = time.perf_counter()
-            with self.tracer.span("READ", chunk=j, zero_copy=1):
+            with self.tracer.span("ola.read", chunk=j, zero_copy=1):
                 view, retries = self.retry.call(
                     lambda: self.store.read_chunk_into(j, out_rows), j)
             dt = time.perf_counter() - t0

@@ -1,6 +1,7 @@
 """Observability: metrics registry, span tracer, per-query explain plane.
 
-Zero third-party dependencies.  ``repro.obs`` imports nothing from the
+Zero third-party imports at import time (the tracers import
+``jax.profiler`` when one is built).  ``repro.obs`` imports nothing from the
 rest of ``repro``, so any layer (data plane, engine, server, benches) can
 depend on it without cycles.
 """
@@ -15,7 +16,9 @@ from repro.obs.metrics import (
 )
 from repro.obs.trace import (
     NULL_TRACER,
+    ROUND_SPAN,
     NullTracer,
+    ProfilerTracer,
     SpanTracer,
     validate_chrome_trace,
 )
@@ -29,6 +32,8 @@ __all__ = [
     "MetricsRegistry",
     "NULL_TRACER",
     "NullTracer",
+    "ProfilerTracer",
+    "ROUND_SPAN",
     "RoundSample",
     "SpanTracer",
     "validate_chrome_trace",

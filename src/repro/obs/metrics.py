@@ -142,8 +142,8 @@ class Histogram:
                 "count": self.total, "sum": self.sum}
 
 
-#: Default latency buckets (modeled seconds): spans the smoke workloads'
-#: sub-millisecond tier-1 answers up through multi-scan residencies.
+#: Default latency buckets (seconds): from sub-millisecond tier-1 answers
+#: up through multi-scan residencies.
 LATENCY_BUCKETS_S = (1e-5, 1e-4, 1e-3, 3e-3, 1e-2, 3e-2, 0.1, 0.3, 1.0,
                      3.0, 10.0, 30.0)
 

@@ -1,32 +1,31 @@
-"""Nested span tracer with chrome-trace (Perfetto) export — zero deps.
+"""Span tracers on the JAX profiler's clock, with chrome-trace export.
 
 The OLA query lifecycle is a pipeline the user is supposed to *watch*:
-submit → admission decision → per-round (claims, kernel, merge, estimate)
-→ retire, with the scan plane's READ / prefetch overlap running underneath
-on the reader thread.  :class:`SpanTracer` records that shape as nested
-spans and exports the standard chrome-trace JSON (``traceEvents`` with
-complete ``"X"`` events), which https://ui.perfetto.dev or
-``chrome://tracing`` open directly.
+submit, admission (synopsis refresh, seed, slot write), then per round
+claims, dispatch, device wait, merge and retirement, with the scan plane's
+READ / prefetch overlap running underneath on the reader thread.  Call
+sites open spans through one protocol, ``tracer.span(name, **args)`` and
+``tracer.round(n)``; the query a span works for travels in its args
+(``qid``, ``slot``, ``plan``, ``outcome``), so the spans of one query share
+its ``qid``.
 
-Design constraints, in order:
+* :class:`ProfilerTracer`, every server's default, emits each span as a
+  ``jax.profiler.TraceAnnotation`` and each round as a
+  ``StepTraceAnnotation``.  The profiler keeps them, while a trace is being
+  recorded, on the same clock as the device's operations, so an idle gap
+  of the device can be put down to the host work under it.  With no trace
+  recording an annotation costs about a microsecond.
+* :class:`SpanTracer` does the same and also records each span into its
+  own bounded buffer, exported as chrome-trace JSON (``traceEvents`` with
+  complete ``"X"`` events) that https://ui.perfetto.dev or
+  ``chrome://tracing`` open directly.
+* :data:`NULL_TRACER` is tracing off: ``span()`` returns one shared no-op
+  context manager.
 
-* **host-side only** — span boundaries wrap host calls (slab assembly, the
-  jitted round dispatch, report reads); nothing jit-visible changes, so a
-  traced run is round-for-round bit-exact with an untraced one;
-* **allocation-light off** — the off state is :data:`NULL_TRACER`, whose
-  ``span()`` returns one shared no-op context manager: the cost of
-  disabled tracing is a method call, not an object graph;
-* **deterministic in tests** — the clock is injected (``clock=`` any
-  zero-arg callable returning seconds); a counter clock makes every
-  timestamp and duration reproducible;
-* **thread-safe** — the prefetcher's reader thread emits READ spans
-  concurrently with the server loop; events carry a small per-thread tid
-  and appends are lock-protected.  Span *nesting* state is thread-local,
-  so cross-thread interleavings can never corrupt a stack;
-* **bounded** — at ``max_events`` the tracer stops recording and counts
-  drops (``dropped``) instead of growing without bound; the exporter
-  stamps the drop count into the trace metadata rather than truncating
-  silently.
+Every span wraps host calls only; nothing jit-visible changes, so a traced
+run is round-for-round bit-exact with an untraced one.  ``jax`` is imported
+when a tracer is built, not when this module is, so ``repro.obs`` stays
+importable without it.
 """
 
 from __future__ import annotations
@@ -35,6 +34,9 @@ import json
 import threading
 import time
 from typing import Callable, Optional
+
+#: Name of the span around one engine round (a profiler step).
+ROUND_SPAN = "ola.round"
 
 
 class _NullSpan:
@@ -53,31 +55,49 @@ _NULL_SPAN = _NullSpan()
 
 
 class NullTracer:
-    """Tracing disabled: every call is a no-op returning shared objects."""
-
-    enabled = False
+    """Tracing disabled: every call returns the shared no-op span."""
 
     def span(self, name: str, **args) -> _NullSpan:
         return _NULL_SPAN
 
-    def event(self, name: str, **args) -> None:
-        return None
+    def round(self, n: int) -> _NullSpan:
+        return _NULL_SPAN
 
 
-#: Module-level singleton — engines and pipelines default their ``tracer``
-#: attribute to this so call sites never need a None check.
+#: Module-level singleton: pass it as ``tracer`` to turn tracing off.
 NULL_TRACER = NullTracer()
 
 
-class _Span:
-    __slots__ = ("tracer", "name", "args", "t0", "depth")
+class ProfilerTracer:
+    """Spans as JAX profiler annotations (see module docstring)."""
 
-    def __init__(self, tracer: "SpanTracer", name: str, args: dict):
+    def __init__(self):
+        from jax import profiler
+
+        self._annotation = profiler.TraceAnnotation
+        self._step = profiler.StepTraceAnnotation
+
+    def span(self, name: str, **args):
+        """Context manager around host work; ``args`` become the
+        annotation's stats (keep them small scalars)."""
+        return self._annotation(name, **args)
+
+    def round(self, n: int):
+        """Context manager around engine round ``n``."""
+        return self._step(ROUND_SPAN, step_num=n)
+
+
+class _Span:
+    __slots__ = ("tracer", "name", "args", "inner", "t0", "depth")
+
+    def __init__(self, tracer: "SpanTracer", name: str, args: dict, inner):
         self.tracer = tracer
         self.name = name
         self.args = args
+        self.inner = inner
 
     def __enter__(self):
+        self.inner.__enter__()
         tr = self.tracer
         stack = tr._stack()
         self.depth = len(stack)
@@ -90,21 +110,25 @@ class _Span:
         t1 = tr.clock()
         tr._stack().pop()
         tr._record(self.name, self.t0, t1 - self.t0, self.depth, self.args)
-        return False
+        return self.inner.__exit__(*exc)
 
 
-class SpanTracer:
-    """Span recorder (see module docstring).
+class SpanTracer(ProfilerTracer):
+    """Profiler annotations plus a chrome-trace buffer (see module
+    docstring).
 
     ``clock`` must be monotone (defaults to :func:`time.perf_counter`);
     timestamps are recorded relative to the tracer's construction so the
-    exported trace starts near zero.
+    exported trace starts near zero.  Spans from several threads are safe:
+    events carry a small per-thread tid, appends are lock-protected and
+    nesting state is thread-local.  At ``max_events`` the buffer stops
+    recording and counts drops (``dropped``), which the export stamps into
+    the trace metadata.
     """
-
-    enabled = True
 
     def __init__(self, clock: Optional[Callable[[], float]] = None,
                  max_events: int = 1_000_000):
+        super().__init__()
         self.clock = clock if clock is not None else time.perf_counter
         self.max_events = int(max_events)
         self.events: list[tuple] = []   # (name, ts, dur, tid, depth, args)
@@ -139,13 +163,10 @@ class SpanTracer:
                  args))
 
     def span(self, name: str, **args) -> _Span:
-        """Context manager timing a nested span; ``args`` become the
-        event's chrome-trace args payload (keep them small scalars)."""
-        return _Span(self, name, args)
+        return _Span(self, name, args, super().span(name, **args))
 
-    def event(self, name: str, **args) -> None:
-        """Instantaneous event (duration 0) at the current clock."""
-        self._record(name, self.clock(), 0.0, len(self._stack()), args)
+    def round(self, n: int) -> _Span:
+        return _Span(self, ROUND_SPAN, {"step_num": n}, super().round(n))
 
     def clear(self) -> None:
         with self._lock:

@@ -40,6 +40,7 @@ import time
 import warnings
 from typing import Optional, Sequence
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -70,7 +71,7 @@ from repro.core.synopsis import BiLevelSynopsis
 from repro.core import estimators as est
 from repro.obs.explain import ExplainRecord, RoundSample
 from repro.obs.metrics import LATENCY_BUCKETS_S, MetricsRegistry
-from repro.obs.trace import NULL_TRACER
+from repro.obs.trace import ProfilerTracer
 from repro.sched.admission import (
     SHED,
     TIER1,
@@ -294,6 +295,9 @@ class WorkloadQuery:
     key: Optional[tuple] = None     # rollup pattern key (None: not cacheable
                                     # or the server runs without a rollup tier)
     explain: Optional[ExplainRecord] = None  # lifecycle explain (repro.obs)
+    t_submitted: float = 0.0        # time.perf_counter() at submit
+    t_enqueued: float = 0.0         # time.perf_counter() at the last queue
+                                    # entry (submit, or eviction)
 
 
 @dataclasses.dataclass
@@ -323,7 +327,8 @@ class WorkloadResult:
     # plan="rollup").  Lets benchmarks separate scan-served answers from
     # cached and degraded ones.
     sched_outcome: str = "admitted"
-    queue_wait: float = 0.0         # t_admit - t_submit (slot wait, modeled s)
+    queue_wait_model_s: float = 0.0  # t_admit - t_submit (slot wait,
+                                     # modeled s)
     slo_met: Optional[bool] = None  # None when the query carried no SLO
     priority: str = "normal"        # SLO priority class (per-class latency
                                     # curves in benchmarks/bench_workload.py)
@@ -354,7 +359,9 @@ class WorkloadResult:
         default=None, compare=False, repr=False)
 
     @property
-    def latency(self) -> float:
+    def latency_model_s(self) -> float:
+        """Submit to retirement on the modeled Eq. (4) clock (wall seconds
+        are the server's ``query_latency_s`` histogram)."""
         return self.t_done - self.t_submit
 
     @property
@@ -404,11 +411,15 @@ class OLAWorkloadServer:
         answer meets their accuracy target.  ``None`` (default) keeps
         every query on the Tier-2 scan path.
 
-        ``tracer`` — a :class:`~repro.obs.trace.SpanTracer` records the
-        query lifecycle (submit → admission → per-round claims/kernel/
-        merge/estimate → retire) and the scan plane's READ/prefetch
-        overlap as nested spans, exportable as chrome-trace JSON.  All
-        instrumentation is host-side: a traced NEUTRAL run is
+        ``tracer`` — where the server's spans go (:mod:`repro.obs.trace`):
+        submit, admission and its per-query parts, and per round the
+        claims, dispatch, device wait, merge and retirement, plus the scan
+        plane's READ/prefetch spans.  The default, a
+        :class:`~repro.obs.trace.ProfilerTracer`, writes them into the JAX
+        profiler's trace while one is recording; a
+        :class:`~repro.obs.trace.SpanTracer` also keeps them for
+        chrome-trace JSON; :data:`~repro.obs.trace.NULL_TRACER` turns them
+        off.  All instrumentation is host-side: a traced NEUTRAL run is
         round-for-round bit-exact with an untraced one.  ``metrics`` — a
         :class:`~repro.obs.metrics.MetricsRegistry` to surface counters
         on; one is created internally when omitted (see
@@ -525,11 +536,11 @@ class OLAWorkloadServer:
         self._slot_retries0 = np.zeros(max_slots, np.int64)
         self._scan_rate = scan_tuples_per_s(store, self.config,
                                             rates=self.rates)
-        # observability: span tracer (no-op singleton when untraced) and
+        # observability: span tracer (profiler annotations by default) and
         # the metrics registry every scattered counter surfaces through
-        self.tracer = tracer if tracer is not None else NULL_TRACER
+        self.tracer = tracer if tracer is not None else ProfilerTracer()
         set_tracer = getattr(self.engine, "set_tracer", None)
-        if set_tracer is not None and self.tracer.enabled:
+        if set_tracer is not None:
             set_tracer(self.tracer)
         self.metrics = metrics if metrics is not None else MetricsRegistry()
         self._bind_metrics()
@@ -550,6 +561,9 @@ class OLAWorkloadServer:
                   fn=lambda: self.tuples_scanned)
         reg.gauge("server_queue_depth", help="queries waiting for a slot",
                   fn=lambda: len(self.queue))
+        self._queue_wait = reg.counter(
+            "server_queue_wait_seconds",
+            help="wall seconds admitted queries waited in the queue")
         reg.gauge("server_slots_resident", help="occupied scan slots",
                   fn=lambda: sum(w is not None for w in self.slot_wq))
         reg.gauge("server_shed_count", help="queries shed (best-effort)",
@@ -658,6 +672,10 @@ class OLAWorkloadServer:
             return
         new = [int(j) for j in log[self._quarantine_seen:]]
         self._quarantine_seen = len(log)
+        with self.tracer.span("ola.quarantine", chunks=len(new)):
+            self._absorb_quarantine(new)
+
+    def _absorb_quarantine(self, new: list[int]) -> None:
         if new:
             # degradation is a per-query fact: every resident query's answer
             # now describes a smaller population — record it on their
@@ -666,8 +684,6 @@ class OLAWorkloadServer:
                 if w is not None and w.explain is not None:
                     w.explain.record_degradation(
                         round=self.rounds, t=self.t_model, chunk_ids=new)
-            if self.tracer.enabled:
-                self.tracer.event("quarantine", chunks=len(new))
         qn = np.asarray(self.state.quarantined)
         self._quarantine_count = int(qn.sum())
         sizes = np.asarray(self.store.chunk_sizes)
@@ -718,6 +734,11 @@ class OLAWorkloadServer:
         or the scan is already fully extracted with no synopsis to answer
         from (the query could never receive a tuple).
         """
+        with self.tracer.span("ola.submit", qid=self._next_qid):
+            return self._submit(query, arrival_t, plan, slo)
+
+    def _submit(self, query: Query, arrival_t: Optional[float],
+                plan: Optional[str], slo: Optional[QuerySLO]) -> int:
         if plan is not None and plan not in PLAN_CODES:
             raise ValueError(
                 f"unknown plan {plan!r}; expected one of {sorted(PLAN_CODES)}")
@@ -741,14 +762,14 @@ class OLAWorkloadServer:
         at = self.t_model if arrival_t is None else float(arrival_t)
         key = (pattern_key(query, self.store.codec.num_cols)
                if self.rollup is not None else None)
+        now = time.perf_counter()
         wq = WorkloadQuery(qid=qid, query=query, arrival_t=at,
                            plan=plan, row=row, slo=slo, key=key,
                            explain=ExplainRecord(qid=qid, name=query.name,
-                                                 t_submit=at))
+                                                 t_submit=at),
+                           t_submitted=now, t_enqueued=now)
         self.queue.append(wq)
         self.queue.sort(key=lambda wq: (wq.arrival_t, wq.qid))
-        if self.tracer.enabled:
-            self.tracer.event("submit", qid=qid, query=query.name)
         return qid
 
     # --------------------------------------------------------- admission ----
@@ -807,8 +828,8 @@ class OLAWorkloadServer:
         """Single retirement funnel for every completion path (tier-1,
         shed, seed-retire, scan-retire): finalize + attach the explain
         record (its final est/CI copied from the result's own floats —
-        bit-for-bit), count the outcome, observe latency, and emit the
-        retire trace event."""
+        bit-for-bit), count the outcome, and observe the wall seconds from
+        submit to now."""
         if wq.explain is not None:
             result.explain = wq.explain.finalize(result)
         self.results.append(result)
@@ -816,12 +837,9 @@ class OLAWorkloadServer:
             "queries_total", help="completed queries by scheduler outcome",
             labels={"outcome": result.sched_outcome}).inc()
         self.metrics.histogram(
-            "query_latency_s", help="submit->done latency (modeled s)",
-            bounds=LATENCY_BUCKETS_S).observe(result.latency)
-        if self.tracer.enabled:
-            self.tracer.event("retire", qid=result.qid,
-                              outcome=result.sched_outcome,
-                              rounds=result.rounds_resident)
+            "query_latency_s", help="submit->retirement latency (wall s)",
+            bounds=LATENCY_BUCKETS_S).observe(
+                time.perf_counter() - wq.t_submitted)
 
     def _admit_ready_scheduled(self) -> None:
         """Scheduler intake: ready queries are considered in queue-policy
@@ -855,16 +873,14 @@ class OLAWorkloadServer:
                     # earlier ones against a stale no-free-slot snapshot
                     restart = True
                     break
-                decision = self._decide_admission(wq, len(free), ahead)
+                with self.tracer.span("ola.decide", qid=wq.qid):
+                    decision = self._decide_admission(wq, len(free), ahead)
                 if wq.explain is not None:
                     wq.explain.admission_reason = decision.reason
                     wq.explain.predicted_service_s = \
                         decision.predicted_service_s
                     wq.explain.predicted_finish_t = \
                         decision.predicted_finish_t
-                if self.tracer.enabled:
-                    self.tracer.event("admission", qid=wq.qid,
-                                      action=decision.action)
                 if decision.action == TIER1 and self._try_tier1(wq):
                     # rollup cache answered: no slot consumed, the slot
                     # picture is unchanged — no restart needed
@@ -878,7 +894,8 @@ class OLAWorkloadServer:
                     self._admit(self._free_slots()[0], wq)
                 elif decision.action == SHED:
                     self.queue.remove(wq)
-                    self._shed(wq)
+                    with self.tracer.span("ola.shed", qid=wq.qid):
+                        self._shed(wq)
                 elif free:
                     self.queue.remove(wq)
                     self._admit(free[0], wq)
@@ -926,12 +943,12 @@ class OLAWorkloadServer:
         re-admission seed, release the slot, and re-queue the occupant
         (flagged ``preempted`` — it completes later, never dropped)."""
         wq = self.slot_wq[s]
-        wq.saved_stats = slot_stats_snapshot(self.state, s)
+        with self.tracer.span("ola.evict", qid=wq.qid, slot=s):
+            wq.saved_stats = slot_stats_snapshot(self.state, s)
         wq.preempted = True
         wq.queued = True
+        wq.t_enqueued = time.perf_counter()
         self.preempt_count += 1
-        if self.tracer.enabled:
-            self.tracer.event("preempt", qid=wq.qid, slot=s)
         self._release(s)
         self.queue.append(wq)
         self.queue.sort(key=lambda w: (w.arrival_t, w.qid))
@@ -1008,7 +1025,8 @@ class OLAWorkloadServer:
             err=err, decision=decision, plan="rollup",
             t_submit=wq.arrival_t, t_admit=now, t_done=now,
             seeded_tuples=m, tuples_seen=m, rounds_resident=0,
-            sched_outcome="tier1", queue_wait=latency, slo_met=slo_met,
+            sched_outcome="tier1", queue_wait_model_s=latency,
+            slo_met=slo_met,
             priority=(wq.slo or NO_SLO).priority,
             degraded=self._quarantine_count > 0,
             chunks_quarantined=self._quarantine_count))
@@ -1182,7 +1200,7 @@ class OLAWorkloadServer:
             t_submit=wq.arrival_t, t_admit=now, t_done=now,
             seeded_tuples=m_seen, tuples_seen=m_seen, rounds_resident=0,
             from_synopsis=from_syn, unserved=unserved, sched_outcome="shed",
-            queue_wait=now - wq.arrival_t, slo_met=slo_met,
+            queue_wait_model_s=now - wq.arrival_t, slo_met=slo_met,
             priority=(wq.slo or NO_SLO).priority,
             degraded=self._quarantine_count > 0,
             chunks_quarantined=self._quarantine_count))
@@ -1192,42 +1210,68 @@ class OLAWorkloadServer:
         self._rollup_on_retire(wq, None, False)
 
     def _admit(self, s: int, wq: WorkloadQuery) -> None:
+        self._queue_wait.inc(time.perf_counter() - wq.t_enqueued)
         plan = wq.plan or select_plan(self.store, self.config, wq.query,
                                       rates=self.rates,
                                       decoded_fraction=self._decoded_fraction())
-        row = wq.row or encode_slot(wq.query, self.store.codec.num_cols,
-                                    max_groups=self.max_groups)
-        row["plan"] = np.int32(PLAN_CODES[plan])
-        self._refresh_synopsis()
+        tr = self.tracer
+        with tr.span("ola.admit_query", qid=wq.qid, slot=s, plan=plan):
+            row = wq.row or encode_slot(wq.query, self.store.codec.num_cols,
+                                        max_groups=self.max_groups)
+            row["plan"] = np.int32(PLAN_CODES[plan])
+            with tr.span("ola.synopsis_refresh"):
+                self._refresh_synopsis()
+            with tr.span("ola.seed"):
+                seed = self._admission_seed(wq)
+                if (self.scheduler is not None and wq.slo is not None
+                        and np.isfinite(wq.slo.target_halfwidth)):
+                    # absolute CI half-width target -> effective relative ε
+                    # for the slot row, anchored on the synopsis magnitude
+                    # estimate (the pass-cached preview — the same one
+                    # admission feasibility used)
+                    _, seed_est, *_ = self._cached_preview(wq)
+                    eps_eff = self.scheduler.effective_epsilon(
+                        wq.query, wq.slo, seed_est)
+                    row["eps"] = np.float32(eps_eff)
+            with tr.span("ola.slot_write"):
+                self._write_slot(s, wq, row, seed, plan)
+            # Section 6.3 best case, per slot: the seed alone may already
+            # meet the target — answer at admission without consuming scan
+            # rounds.  No top-up here: while the newcomer is live its
+            # accuracy votes keep chunks from closing early, and if the scan
+            # still winds down before it is satisfied, step()'s exhausted
+            # branch re-opens chunks then — top-up passes happen only when
+            # provably needed.
+            if seed is not None:
+                with tr.span("ola.seed_retire"):
+                    self._try_retire_from_seed(s, wq)
+
+    def _admission_seed(self, wq: WorkloadQuery) -> Optional[dict]:
+        """The statistics row a newcomer's slot starts from, or None."""
         if wq.saved_stats is not None:
             # preempted query returning to a slot: its eviction snapshot is
             # the seed — every tuple it already counted, at full per-chunk
             # resolution (strictly richer than the synopsis)
-            seed = wq.saved_stats
-        else:
-            seed = self.synopsis.seed_slot(wq.query) if self.synopsis else None
-            if self.rollup is not None and wq.key is not None:
-                cell = self.rollup.get(wq.key)
-                if cell is not None and (
-                        seed is None or int(cell.m.sum())
-                        > int(np.asarray(seed["m"]).sum())):
-                    # Tier-2 with a Tier-1 discount: the cell alone missed
-                    # the target, but it out-samples the synopsis — the
-                    # slot starts from the cached partial aggregate and
-                    # scans only the remainder (both are permutation-window
-                    # samples inside the scanned prefix, so future round
-                    # deltas compose without overlap)
-                    seed = cell.seed_dict()
-        if (self.scheduler is not None and wq.slo is not None
-                and np.isfinite(wq.slo.target_halfwidth)):
-            # absolute CI half-width target -> effective relative ε for the
-            # slot row, anchored on the synopsis magnitude estimate (the
-            # pass-cached preview — the same one admission feasibility used)
-            _, seed_est, *_ = self._cached_preview(wq)
-            eps_eff = self.scheduler.effective_epsilon(wq.query, wq.slo,
-                                                       seed_est)
-            row["eps"] = np.float32(eps_eff)
+            return wq.saved_stats
+        seed = self.synopsis.seed_slot(wq.query) if self.synopsis else None
+        if self.rollup is not None and wq.key is not None:
+            cell = self.rollup.get(wq.key)
+            if cell is not None and (
+                    seed is None or int(cell.m.sum())
+                    > int(np.asarray(seed["m"]).sum())):
+                # Tier-2 with a Tier-1 discount: the cell alone missed the
+                # target, but it out-samples the synopsis — the slot starts
+                # from the cached partial aggregate and scans only the
+                # remainder (both are permutation-window samples inside the
+                # scanned prefix, so future round deltas compose without
+                # overlap)
+                seed = cell.seed_dict()
+        return seed
 
+    def _write_slot(self, s: int, wq: WorkloadQuery, row: dict,
+                    seed: Optional[dict], plan: str) -> None:
+        """Give slot ``s`` to ``wq``: its statistics row from ``seed``, its
+        slot-table row, fresh group cells, and the host bookkeeping."""
         n = self.store.num_chunks
         stats, seeded = slot_stats_write(self.state.stats, s, seed, n)
         self.state = self.state._replace(
@@ -1277,17 +1321,6 @@ class OLAWorkloadServer:
                 row.get("eps", wq.query.epsilon))
             if not wq.explain.admission_reason:
                 wq.explain.admission_reason = "fifo: free slot"
-        if self.tracer.enabled:
-            self.tracer.event("admit", qid=wq.qid, slot=s, plan=plan)
-
-        # Section 6.3 best case, per slot: the seed alone may already meet
-        # the target — answer at admission without consuming scan rounds.
-        # No top-up here: while the newcomer is live its accuracy votes keep
-        # chunks from closing early, and if the scan still winds down before
-        # it is satisfied, step()'s exhausted branch re-opens chunks then —
-        # top-up passes happen only when provably needed.
-        if seed is not None:
-            self._try_retire_from_seed(s, wq)
 
     def _try_retire_from_seed(self, s: int, wq: WorkloadQuery) -> bool:
         q = wq.query
@@ -1327,7 +1360,8 @@ class OLAWorkloadServer:
             tuples_seen=int(np.asarray(self.state.stats.m[s]).sum()),
             rounds_resident=0, from_synopsis=True,
             sched_outcome=self._outcome(wq),
-            queue_wait=self.slot_admit_t[s] - wq.arrival_t, slo_met=slo_met,
+            queue_wait_model_s=self.slot_admit_t[s] - wq.arrival_t,
+            slo_met=slo_met,
             priority=(wq.slo or NO_SLO).priority,
             degraded=self._quarantine_count > 0,
             chunks_quarantined=self._quarantine_count,
@@ -1470,11 +1504,10 @@ class OLAWorkloadServer:
             gval[:len(tracked)] = np.asarray(tracked, np.float32)
             gact[:len(tracked)] = 1.0
             gact[g - 1] = 1.0   # __other__ stays live
-            self.table = slot_table_set_groups(self.table, s, gval, gact)
-            self.state = zero_group_cells(self.state, s, cells=[g - 1])
-            if self.tracer.enabled:
-                self.tracer.event("group_promote", qid=wq.qid, slot=s,
-                                  values=[float(v) for v in new])
+            with self.tracer.span("ola.group_promote", qid=wq.qid, slot=s,
+                                  values=len(new)):
+                self.table = slot_table_set_groups(self.table, s, gval, gact)
+                self.state = zero_group_cells(self.state, s, cells=[g - 1])
 
     # -------------------------------------------------------------- step ----
     def _retire_finished(self, rep, unserved: frozenset = frozenset()) -> None:
@@ -1489,45 +1522,51 @@ class OLAWorkloadServer:
             # after the scan became a census) has no answer: flag it
             # unserved rather than reporting a fabricated zero
             bad = s in unserved or int(m_rows[s].sum()) == 0
-            lo_f, hi_f = float(rep.lo[s]), float(rep.hi[s])
-            slo_met = None
-            if wq.slo is not None:
-                slo_met = wq.slo.met(self.t_model - wq.arrival_t,
-                                     float("nan") if bad
-                                     else (hi_f - lo_f) / 2.0)
-            if wq.explain is not None and not wq.explain.tier_reason:
-                wq.explain.tier_reason = (
-                    "scan exhausted before the slot saw any tuple" if bad
-                    else "scan-served: retired at its stop condition")
-            self._finish(wq, WorkloadResult(
-                qid=wq.qid, name=wq.query.name,
-                estimate=float("nan") if bad else float(rep.estimate[s]),
-                lo=lo_f,
-                hi=hi_f, err=float(rep.err[s]),
-                decision=int(rep.decided[s]), plan=self.slot_plan[s],
-                t_submit=wq.arrival_t, t_admit=self.slot_admit_t[s],
-                t_done=self.t_model, seeded_tuples=int(self.slot_seeded[s]),
-                tuples_seen=int(np.asarray(self.state.stats.m[s]).sum()),
-                rounds_resident=int(self.rounds - self.slot_admit_round[s]),
-                unserved=bad,
-                sched_outcome=self._outcome(wq),
-                queue_wait=float(self.slot_admit_t[s] - wq.arrival_t),
-                slo_met=slo_met,
-                priority=(wq.slo or NO_SLO).priority,
-                degraded=self._quarantine_count > 0,
-                chunks_quarantined=self._quarantine_count,
-                read_retries=max(self._pipeline_retries()
-                                 - int(self._slot_retries0[s]), 0),
-                groups=None if bad else self._group_results(rep, s, wq)))
-            service = self.t_model - self.slot_admit_t[s]
-            self._service_times.append(service)
-            if self.scheduler is not None:
-                # feed the per-class service-time sketch (quantile admission)
-                self.scheduler.observe_service(wq.slo, service)
-            self._rollup_on_retire(wq, s, not bad)
-            if not bad:
-                self._rollup_group_cells(wq, s)
-            self._release(s)
+            with self.tracer.span("ola.retire_query", qid=wq.qid, slot=s,
+                                  outcome=self._outcome(wq)):
+                self._retire_slot(rep, s, wq, bad)
+
+    def _retire_slot(self, rep, s: int, wq: WorkloadQuery,
+                     bad: bool) -> None:
+        lo_f, hi_f = float(rep.lo[s]), float(rep.hi[s])
+        slo_met = None
+        if wq.slo is not None:
+            slo_met = wq.slo.met(self.t_model - wq.arrival_t,
+                                 float("nan") if bad
+                                 else (hi_f - lo_f) / 2.0)
+        if wq.explain is not None and not wq.explain.tier_reason:
+            wq.explain.tier_reason = (
+                "scan exhausted before the slot saw any tuple" if bad
+                else "scan-served: retired at its stop condition")
+        self._finish(wq, WorkloadResult(
+            qid=wq.qid, name=wq.query.name,
+            estimate=float("nan") if bad else float(rep.estimate[s]),
+            lo=lo_f,
+            hi=hi_f, err=float(rep.err[s]),
+            decision=int(rep.decided[s]), plan=self.slot_plan[s],
+            t_submit=wq.arrival_t, t_admit=self.slot_admit_t[s],
+            t_done=self.t_model, seeded_tuples=int(self.slot_seeded[s]),
+            tuples_seen=int(np.asarray(self.state.stats.m[s]).sum()),
+            rounds_resident=int(self.rounds - self.slot_admit_round[s]),
+            unserved=bad,
+            sched_outcome=self._outcome(wq),
+            queue_wait_model_s=float(self.slot_admit_t[s] - wq.arrival_t),
+            slo_met=slo_met,
+            priority=(wq.slo or NO_SLO).priority,
+            degraded=self._quarantine_count > 0,
+            chunks_quarantined=self._quarantine_count,
+            read_retries=max(self._pipeline_retries()
+                             - int(self._slot_retries0[s]), 0),
+            groups=None if bad else self._group_results(rep, s, wq)))
+        service = self.t_model - self.slot_admit_t[s]
+        self._service_times.append(service)
+        if self.scheduler is not None:
+            # feed the per-class service-time sketch (quantile admission)
+            self.scheduler.observe_service(wq.slo, service)
+        self._rollup_on_retire(wq, s, not bad)
+        if not bad:
+            self._rollup_group_cells(wq, s)
+        self._release(s)
 
     def _any_active(self) -> bool:
         return any(wq is not None for wq in self.slot_wq)
@@ -1624,31 +1663,38 @@ class OLAWorkloadServer:
         """Admit ready arrivals, run one engine round, retire finished
         queries.  Returns False when there is nothing to do right now."""
         tr = self.tracer
-        self._admit_ready()
+        with tr.span("ola.admit"):
+            self._admit_ready()
         if not self._any_active():
             return False
-        with tr.span("round", round=self.rounds):
+        with tr.round(self.rounds):
             if self.scheduler is not None:
-                self._apply_scheduling()
-            b = self.engine.budget_ladder(float(self.state.budget))
+                with tr.span("ola.schedule"):
+                    self._apply_scheduling()
             # round_data: the packed device view, or (stream residency) a
             # slab assembled from the predicted claims — which also covers
             # top-up passes, since _begin_topup_pass rewrites cur/head
             # *before* the prediction runs, so re-opened chunks are
             # re-requested from the prefetcher exactly when a worker is
             # about to claim them
-            with tr.span("claims"):
+            with tr.span("ola.claims"):
+                b = self.engine.budget_ladder(float(self.state.budget))
                 self.state, data = self.engine.round_data(self.state)
-            # a failed read may have quarantined chunks inside round_data:
-            # fold the survivors into every population-priced structure
-            # before the round estimates over them
-            self._note_quarantine()
-            mode, data = self.engine.data_mode(data)
-            with tr.span("kernel", b=b, mode=mode):
+                # a failed read may have quarantined chunks inside
+                # round_data: fold the survivors into every
+                # population-priced structure before the round estimates
+                # over them
+                self._note_quarantine()
+                mode, data = self.engine.data_mode(data)
+            with tr.span("ola.dispatch", b=b, mode=mode):
                 self.state, rep = self.engine.round_fn(b, mode)(
                     self.state, self.table, data, self.engine.speeds)
             self.rounds += 1
-            with tr.span("merge"):
+            # the report's first host read waits for the device anyway;
+            # waiting here names that time
+            with tr.span("ola.device_wait"):
+                jax.block_until_ready(rep)
+            with tr.span("ola.merge"):
                 if self.rollup is not None and self.rollup.cells:
                     # incremental maintenance: resident slots running a
                     # promoted pattern fold their round-accumulated stats
@@ -1661,22 +1707,32 @@ class OLAWorkloadServer:
                            is not None]
                     for s, row in slot_stats_fold(self.state, ids).items():
                         self.rollup.fold(self.slot_wq[s].key, row)
-            with tr.span("estimate"):
-                self._record_trajectory(rep, b)
-                if self.scheduler is not None:
-                    # next round's ε-distance claim weights read this report
-                    self._last_err = np.asarray(rep.err, float)
-                if (self.scheduler is not None
-                        and self.scheduler.config.deadline_enforcement):
-                    self._enforce_deadlines()
-                self._retire_finished(rep)
-                self._fold_group_discovery(rep)
-                if self._any_active() and bool(rep.exhausted):
-                    if not self._begin_topup_pass():
-                        # census complete: estimates are as good as they
-                        # will get
-                        self._force_retire_exhausted(rep)
+            with tr.span("ola.retire"):
+                self._retire_round(rep, b)
         return True
+
+    def _retire_round(self, rep, b) -> None:
+        """Read the round's report: explain trajectories, retirements, group
+        discovery, and a top-up pass or the census at exhaustion."""
+        tr = self.tracer
+        with tr.span("ola.report"):
+            self._record_trajectory(rep, b)
+            if self.scheduler is not None:
+                # next round's ε-distance claim weights read this report
+                self._last_err = np.asarray(rep.err, float)
+        with tr.span("ola.retire_slots"):
+            if (self.scheduler is not None
+                    and self.scheduler.config.deadline_enforcement):
+                self._enforce_deadlines()
+            self._retire_finished(rep)
+        with tr.span("ola.groups"):
+            self._fold_group_discovery(rep)
+        with tr.span("ola.topup"):
+            if self._any_active() and bool(rep.exhausted):
+                if not self._begin_topup_pass():
+                    # census complete: estimates are as good as they will
+                    # get
+                    self._force_retire_exhausted(rep)
 
     def _force_retire_exhausted(self, rep) -> None:
         """Every chunk is fully extracted; retire survivors with their final

@@ -27,7 +27,12 @@ from repro.data.faults import FaultConfig, FaultInjector, RetryPolicy
 from repro.data.generator import make_synthetic_zipf, store_dataset
 from repro.obs.explain import ExplainRecord, RoundSample
 from repro.obs.metrics import Counter, Gauge, MetricsRegistry
-from repro.obs.trace import NULL_TRACER, SpanTracer, validate_chrome_trace
+from repro.obs.trace import (
+    NULL_TRACER,
+    ProfilerTracer,
+    SpanTracer,
+    validate_chrome_trace,
+)
 from repro.sched import WorkloadScheduler
 from repro.sched.scheduler import NEUTRAL
 from repro.serve.ola_server import OLAWorkloadServer, ServerOptions
@@ -126,13 +131,13 @@ def test_tracer_deterministic_under_injected_clock():
 
 
 def test_null_tracer_records_nothing():
-    assert NULL_TRACER.enabled is False
-    with NULL_TRACER.span("x", a=1):
-        NULL_TRACER.event("y")
+    with NULL_TRACER.span("x", a=1), NULL_TRACER.round(0) as r:
+        assert r is NULL_TRACER.span("y")       # one shared no-op object
     # and the real tracer's buffer caps instead of growing without bound
     tr = SpanTracer(max_events=2)
     for i in range(5):
-        tr.event(f"e{i}")
+        with tr.span(f"e{i}"):
+            pass
     assert len(tr.events) == 2 and tr.dropped == 3
 
 
@@ -253,7 +258,7 @@ def test_tier1_answer_has_zero_round_trajectory(setup):
 
 def _answer_key(results):
     return [(r.qid, repr(r.estimate), repr(r.lo), repr(r.hi),
-             repr(r.latency), r.sched_outcome, r.rounds_resident,
+             repr(r.latency_model_s), r.sched_outcome, r.rounds_resident,
              r.tuples_seen) for r in results]
 
 
@@ -274,14 +279,25 @@ def test_neutral_server_bit_exact_with_tracing_on(setup):
         srv.close()
         return res, stats, srv
 
-    res_off, stats_off, _ = _run(None)
+    # the default (profiler annotations), tracing off, and the chrome-trace
+    # buffer give the same answers, bit for bit
+    res_off, stats_off, _ = _run(NULL_TRACER)
+    res_def, stats_def, srv_def = _run(None)
     res_on, stats_on, srv_on = _run(SpanTracer())
+    assert isinstance(srv_def.tracer, ProfilerTracer)
+    assert _answer_key(res_def) == _answer_key(res_off)
     assert _answer_key(res_on) == _answer_key(res_off)
-    assert stats_on == stats_off
+    assert stats_def == stats_off and stats_on == stats_off
     doc = srv_on.tracer.to_chrome_trace()
     assert validate_chrome_trace(doc) == []
     names = {e["name"] for e in doc["traceEvents"] if e["ph"] == "X"}
-    assert {"round", "claims", "kernel", "merge", "estimate"} <= names
+    assert {"ola.submit", "ola.admit", "ola.admit_query", "ola.round",
+            "ola.schedule", "ola.claims", "ola.dispatch", "ola.device_wait",
+            "ola.merge", "ola.retire", "ola.report", "ola.retire_slots",
+            "ola.groups", "ola.topup", "ola.retire_query"} <= names
+    assert not names & {"round", "kernel", "estimate"}
+    rounds = [e for e in doc["traceEvents"] if e["name"] == "ola.round"]
+    assert len(rounds) == stats_on[0]
 
 
 def test_metrics_snapshot_counts_lifecycle(setup):
@@ -301,6 +317,10 @@ def test_metrics_snapshot_counts_lifecycle(setup):
     assert snap["server_rounds"] == srv.rounds > 0
     assert snap["server_tuples_scanned"] == srv.tuples_scanned
     assert snap["query_latency_s"]["count"] == 3
+    # wall seconds: every query waited in the queue before its slot, and
+    # each answer came later than its submit
+    assert snap["server_queue_wait_seconds"] > 0
+    assert snap["query_latency_s"]["sum"] >= snap["server_queue_wait_seconds"]
     assert snap["quarantine_log"] == []
     assert snap['admission_decisions{action="admitted"}'] >= 1
     # the text exposition renders the same registry without raising

@@ -214,7 +214,7 @@ def test_neutral_scheduler_parity(setup, residency):
             (int(s.tuples_scanned), int(np.asarray(s.state.head)))))
         out = [(r.qid, r.estimate, r.lo, r.hi, r.err, r.tuples_seen,
                 r.t_admit, r.t_done, r.rounds_resident, r.sched_outcome,
-                r.queue_wait, r.from_synopsis) for r in res]
+                r.queue_wait_model_s, r.from_synopsis) for r in res]
         rounds, tuples = srv.rounds, srv.tuples_scanned
         srv.close()
         return out, trace, rounds, tuples
@@ -320,9 +320,9 @@ def test_priority_pressure_meets_deadline(setup):
     sched_cfg = SchedulerConfig(shed_enabled=False)
     # measure both policies on the same workload (no deadline yet)
     probe = QuerySLO(priority="interactive")
-    lat_fifo = _pressure_run(store, probe, None)["hot"].latency
+    lat_fifo = _pressure_run(store, probe, None)["hot"].latency_model_s
     lat_pri = _pressure_run(
-        store, probe, WorkloadScheduler(sched_cfg))["hot"].latency
+        store, probe, WorkloadScheduler(sched_cfg))["hot"].latency_model_s
     assert lat_pri < lat_fifo, (lat_pri, lat_fifo)
     # a deadline between the two: scheduler meets it, FIFO provably misses
     deadline = (lat_pri + lat_fifo) / 2.0
