@@ -46,7 +46,7 @@ from typing import Optional, Sequence
 import jax.numpy as jnp
 import numpy as np
 
-from repro.core.queries import Query, compile_queries
+from repro.core.queries import Query, compile_queries, linear_plan
 
 
 @dataclasses.dataclass
@@ -57,6 +57,24 @@ class SynopsisChunk:
     @property
     def count(self) -> int:
         return int(self.values.shape[0])
+
+
+def _evaluate(queries: Sequence[Query], cols: np.ndarray, num_cols: int):
+    """``cols (T, C) float32 -> (x, p) (Q, T)``, the semantics of
+    ``slot_evaluate``: ``lo <= c < hi`` on every column, ``x = (cols @
+    coeffs) · p``, ``x = p`` for COUNT.  Runs in numpy at float32: the
+    synopsis is host memory, and the work is far below one device round
+    trip.  A query outside the coefficient form (``Custom``) takes the JAX
+    evaluator, once, on the whole array."""
+    try:
+        lp = linear_plan(queries, num_cols)
+    except ValueError:
+        x, p = compile_queries(queries)(jnp.asarray(cols))
+        return np.asarray(x), np.asarray(p)
+    c = cols[:, None, :]                                    # (T, 1, C)
+    p = np.all((c >= lp.lo) & (c < lp.hi), axis=-1).T.astype(np.float32)
+    count = np.asarray([q.agg == "count" for q in queries])[:, None]
+    return np.where(count, np.float32(1), lp.coeffs @ cols.T) * p, p
 
 
 class BiLevelSynopsis:
@@ -179,28 +197,39 @@ class BiLevelSynopsis:
         v = np.maximum(ss / np.maximum(m - 1.0, 1.0), 0.0)
         return v.max(axis=0)
 
+    def _window_stats(self, queries: Sequence[Query]):
+        """Per-chunk sufficient statistics of ``queries`` over every cached
+        window (Section 6.3), in one pass: the windows are stacked into one
+        ``(T, C)`` float32 array, evaluated once, and summed by chunk id in
+        float64.  Returns ``(m (N,) int32, ysum, ysq, psum (Q, N) float32)``.
+        """
+        n = self.n_chunks
+        ids = np.fromiter(self.chunks, np.int64, len(self.chunks))
+        windows = [ch.values for ch in self.chunks.values()]
+        seg = np.repeat(ids, [len(v) for v in windows])
+        cols = (np.concatenate(windows) if windows
+                else np.zeros((0, self.num_cols)))
+        x, p = _evaluate(queries, cols.astype(np.float32, copy=False),
+                         self.num_cols)
+        x = x.astype(np.float64)
+
+        def by_chunk(w):                      # (Q, T) -> (Q, N)
+            return np.stack([np.bincount(seg, wq, n) for wq in w]
+                            ).astype(np.float32)
+
+        return (np.bincount(seg, minlength=n).astype(np.int32),
+                by_chunk(x), by_chunk(x * x), by_chunk(p))
+
     def seed(self, queries: Sequence[Query], cache_cap: int) -> dict:
         """Engine seed for a follow-up query (Section 6.3): evaluate the new
         queries over the cached tuples and pre-fill stats + cursors."""
-        qn = len(queries)
         n = self.n_chunks
-        evaluate = compile_queries(queries)
-        m = np.zeros(n, np.int32)
-        ysum = np.zeros((qn, n), np.float32)
-        ysq = np.zeros((qn, n), np.float32)
-        psum = np.zeros((qn, n), np.float32)
+        m, ysum, ysq, psum = self._window_stats(queries)
         offset = np.zeros(n, np.int32)
         cache = np.zeros((n, cache_cap, self.num_cols), np.float32)
         for j, ch in self.chunks.items():
             if ch.count == 0:
                 continue
-            x, p = evaluate(jnp.asarray(ch.values, jnp.float32))
-            x = np.asarray(x)
-            p = np.asarray(p)
-            m[j] = ch.count
-            ysum[:, j] = x.sum(-1)
-            ysq[:, j] = (x * x).sum(-1)
-            psum[:, j] = p.sum(-1)
             offset[j] = ch.start + ch.count   # cursor continues past the window
             rows = min(ch.count, cache_cap)
             cache[j, :rows] = ch.values[:rows]
@@ -222,23 +251,8 @@ class BiLevelSynopsis:
         """
         if not self.chunks or not self.supports([query]):
             return None
-        n = self.n_chunks
-        evaluate = compile_queries([query])
-        m = np.zeros(n, np.int32)
-        ysum = np.zeros(n, np.float32)
-        ysq = np.zeros(n, np.float32)
-        psum = np.zeros(n, np.float32)
-        for j, ch in self.chunks.items():
-            if ch.count == 0:
-                continue
-            x, p = evaluate(jnp.asarray(ch.values, jnp.float32))
-            x = np.asarray(x)[0]
-            p = np.asarray(p)[0]
-            m[j] = ch.count
-            ysum[j] = x.sum()
-            ysq[j] = (x * x).sum()
-            psum[j] = p.sum()
-        return dict(m=m, ysum=ysum, ysq=ysq, psum=psum)
+        m, ysum, ysq, psum = self._window_stats([query])
+        return dict(m=m, ysum=ysum[0], ysq=ysq[0], psum=psum[0])
 
     def plan_schedule(self, base_schedule: np.ndarray,
                       by_variance: Optional[np.ndarray] = None) -> np.ndarray:

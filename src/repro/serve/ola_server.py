@@ -564,6 +564,12 @@ class OLAWorkloadServer:
         self._queue_wait = reg.counter(
             "server_queue_wait_seconds",
             help="wall seconds admitted queries waited in the queue")
+        self._synopsis_seeds = reg.counter(
+            "server_synopsis_seeds_total",
+            help="seed rows computed from the synopsis")
+        self._synopsis_seed_tuples = reg.counter(
+            "server_synopsis_seed_tuples_total",
+            help="cached tuples evaluated for synopsis seeds")
         reg.gauge("server_slots_resident", help="occupied scan slots",
                   fn=lambda: sum(w is not None for w in self.slot_wq))
         reg.gauge("server_shed_count", help="queries shed (best-effort)",
@@ -1043,8 +1049,8 @@ class OLAWorkloadServer:
         if self.rollup is None or wq.key is None:
             return
         promoted = self.rollup.observe(wq.query, wq.key, self.t_model)
-        if promoted is not None and self.synopsis is not None:
-            seed = self.synopsis.seed_slot(wq.query)
+        if promoted is not None:
+            seed = self._synopsis_seed(wq.query)
             if seed is not None:
                 promoted.fold(seed)
         if s is not None and valid:
@@ -1142,8 +1148,7 @@ class OLAWorkloadServer:
         once).  Single construction shared by admission feasibility, the
         effective-ε translation, shedding, and the rollup preview."""
         if seed is None:
-            if self.synopsis is not None:
-                seed = self.synopsis.seed_slot(query)
+            seed = self._synopsis_seed(query)
             if self.rollup is not None and key is not None:
                 cell = self.rollup.get(key)
                 if cell is not None and (
@@ -1253,7 +1258,7 @@ class OLAWorkloadServer:
             # the seed — every tuple it already counted, at full per-chunk
             # resolution (strictly richer than the synopsis)
             return wq.saved_stats
-        seed = self.synopsis.seed_slot(wq.query) if self.synopsis else None
+        seed = self._synopsis_seed(wq.query)
         if self.rollup is not None and wq.key is not None:
             cell = self.rollup.get(wq.key)
             if cell is not None and (
@@ -1266,6 +1271,15 @@ class OLAWorkloadServer:
                 # scanned prefix, so future round deltas compose without
                 # overlap)
                 seed = cell.seed_dict()
+        return seed
+
+    def _synopsis_seed(self, query: Query) -> Optional[dict]:
+        """The synopsis's statistics row for ``query``, or None; every row
+        served is counted with the cached tuples it was evaluated on."""
+        seed = self.synopsis.seed_slot(query) if self.synopsis else None
+        if seed is not None:
+            self._synopsis_seeds.inc()
+            self._synopsis_seed_tuples.inc(int(seed["m"].sum()))
         return seed
 
     def _write_slot(self, s: int, wq: WorkloadQuery, row: dict,

@@ -230,6 +230,35 @@ def test_server_synopsis_answer_matches_truth(setup):
     assert abs(rep.estimate - truth) / truth < 3 * 0.10
 
 
+@pytest.mark.parametrize("budget", [4096, 0])
+def test_synopsis_seed_counters(setup, budget):
+    """A query admitted mid-scan is seeded from the synopsis, and the
+    registry counts the seed and the cached tuples it was evaluated on; a
+    server without a synopsis counts none."""
+    vals, store = setup
+    cfg = EngineConfig(num_workers=2, seed=19)
+    srv = OLAWorkloadServer(
+              store, cfg,
+              options=ServerOptions(max_slots=2,
+                                    synopsis_budget_tuples=budget))
+    srv.submit(Query(agg="sum", expr=Linear(COEF), epsilon=0.01,
+                     name="warm"), arrival_t=0.0)
+    for _ in range(3):
+        srv.step()
+    srv.submit(Query(agg="count", pred=Range(1, 0.0, 5e7), epsilon=0.01,
+                     name="late"))
+    srv.step()
+    snap = srv.metrics_snapshot()
+    srv.close()
+    seeds = snap["server_synopsis_seeds_total"]
+    tuples = snap["server_synopsis_seed_tuples_total"]
+    if budget:
+        assert seeds >= 1
+        assert 0 < tuples <= seeds * budget
+    else:
+        assert seeds == 0 and tuples == 0
+
+
 # ---------------------------------------------------------------------------
 # Plan selector + top-up
 # ---------------------------------------------------------------------------

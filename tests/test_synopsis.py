@@ -5,7 +5,8 @@ import pytest
 
 from repro.core.controller import EstimationController
 from repro.core.engine import EngineConfig
-from repro.core.queries import Custom, Linear, Query, Range, TRUE
+from repro.core.queries import (
+    TRUE, Custom, Linear, Query, Range, SquaredDiff, compile_queries)
 from repro.core.synopsis import BiLevelSynopsis, SynopsisChunk
 from repro.data.generator import make_synthetic_zipf, store_dataset
 from repro.sampling.permutation import chunk_seed, feistel_permute
@@ -66,6 +67,96 @@ def test_seed_evaluates_new_query():
                                (2 * vals[:, 0] * sel).sum(), rtol=1e-5)
     assert seed["m"][1] == 20
     assert seed["offset"][1] == 25     # cursor continues past the window
+
+
+def _per_chunk_seed(syn, queries):
+    """The reference: each cached window evaluated on its own with the JAX
+    evaluator, sums taken per window."""
+    evaluate = compile_queries(queries)
+    n, qn = syn.n_chunks, len(queries)
+    m = np.zeros(n, np.int32)
+    ysum, ysq, psum = (np.zeros((qn, n), np.float32) for _ in range(3))
+    for j, ch in syn.chunks.items():
+        x, p = evaluate(jnp.asarray(ch.values, jnp.float32))
+        x, p = np.asarray(x), np.asarray(p)
+        m[j] = ch.count
+        ysum[:, j] = x.sum(-1)
+        ysq[:, j] = (x * x).sum(-1)
+        psum[:, j] = p.sum(-1)
+    return dict(m=m, ysum=ysum, ysq=ysq, psum=psum)
+
+
+def _cap_windows(cap=64, n=12, num_cols=8):
+    """A synopsis whose cached chunks (all but chunk 0) hold ``cap``-tuple
+    windows of values on the table's [0, 1e8) scale; each window's first
+    rows sit on the bounds of ``RANGE`` and of the COUNT's range."""
+    syn = BiLevelSynopsis(n_chunks=n, num_cols=num_cols, budget_tuples=4096,
+                          chunk_sizes=np.full(n, 1000))
+    rng = np.random.default_rng(7)
+    for j in range(1, n):
+        vals = rng.uniform(0, 1e8, (cap, num_cols)).astype(np.float32)
+        vals[:3] = np.asarray([[2e7], [7e7], [5e7]], np.float32)
+        syn.chunks[j] = SynopsisChunk(start=3 * j, values=vals)
+    return syn
+
+
+def _one_tuple_window(syn):
+    syn.chunks[4] = SynopsisChunk(start=9, values=syn.chunks[4].values[:1])
+
+
+def _dropped(syn):
+    assert syn.drop_chunks([2, 5, 6]) == 3
+
+
+def _shrunk(syn):
+    syn.budget = 200
+    syn._fit_budget(np.linspace(1.0, 50.0, syn.n_chunks))
+    assert syn.total_tuples <= 200
+
+
+RANGE = Range(2, 2e7, 7e7)
+
+
+@pytest.mark.parametrize("query,mutate", [
+    (Query(agg="sum", expr=Linear(COEF), pred=RANGE), None),
+    (Query(agg="count", pred=RANGE), None),
+    (Query(agg="avg", expr=Linear(COEF), pred=RANGE), None),
+    (Query(agg="sum", expr=Linear(COEF), pred=TRUE), None),
+    (Query(agg="sum", expr=SquaredDiff(0, 3), pred=RANGE), None),
+    (Query(agg="sum", expr=Linear(COEF), pred=RANGE), _one_tuple_window),
+    (Query(agg="sum", expr=Linear(COEF), pred=RANGE), _dropped),
+    (Query(agg="sum", expr=Linear(COEF), pred=RANGE), _shrunk),
+], ids=["sum_range", "count", "avg", "no_predicate", "nonlinear_expr",
+        "one_tuple_window", "after_drop_chunks", "after_fit_budget"])
+def test_packed_seed_matches_per_chunk_loop(query, mutate):
+    """``seed_slot`` and ``seed`` evaluate every cached window in one packed
+    pass; the statistics match the per-window evaluation they replace."""
+    syn = _cap_windows()
+    if mutate is not None:
+        mutate(syn)
+    both = [query, Query(agg="count", pred=Range(5, 0.0, 5e7))]
+    ref = _per_chunk_seed(syn, both)
+    slot_ref = {k: v if k == "m" else v[0] for k, v in ref.items()}
+    for out, want in ((syn.seed_slot(query), slot_ref),
+                      (syn.seed(both, cache_cap=64), ref)):
+        np.testing.assert_array_equal(out["m"], want["m"])
+        assert out["m"].dtype == np.int32
+        for k in ("ysum", "ysq", "psum"):
+            assert out[k].dtype == np.float32
+            assert out[k].shape == want[k].shape
+            np.testing.assert_allclose(out[k], want[k], rtol=1e-5,
+                                       err_msg=k)
+
+
+@pytest.mark.parametrize("case", ["empty", "uncached_column"])
+def test_seed_slot_none_without_windows_or_columns(case):
+    syn = _cap_windows()
+    q = Query(agg="sum", expr=Linear(COEF), pred=Range(6, 0.0, 5e7))
+    if case == "empty":
+        syn.chunks.clear()
+    else:
+        syn.columns_cached = frozenset(range(6))
+    assert syn.seed_slot(q) is None
 
 
 def test_plan_schedule_uncached_first():
