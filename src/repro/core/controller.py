@@ -229,21 +229,12 @@ def _answer_from_stats(queries, stats):
 
     from repro.core import estimators as E
 
-    ests, vars_ = [], []
-    for qi, q in enumerate(queries):
-        if q.agg == "sum":
-            t = E.tau_hat(stats)[qi]
-            v = E.var_hat(stats)[0][qi]
-        elif q.agg == "count":
-            t = E.count_tau_hat(stats)[qi]
-            v = E.count_var_hat(stats)[0][qi]
-        else:
-            r, vv, _ = E.avg_estimate(stats)
-            t, v = r[qi], vv[qi]
-        ests.append(t)
-        vars_.append(v)
-    est_v = jnp.stack(ests)
-    var_v = jnp.stack(vars_)
+    sum_t, sum_v, cnt_t, cnt_v, avg_t, avg_v = E.bilevel_estimates(
+        stats, aggs={q.agg for q in queries})
+    pick = {"sum": (sum_t, sum_v), "count": (cnt_t, cnt_v),
+            "avg": (avg_t, avg_v)}
+    est_v = jnp.stack([pick[q.agg][0][qi] for qi, q in enumerate(queries)])
+    var_v = jnp.stack([pick[q.agg][1][qi] for qi, q in enumerate(queries)])
     lo, hi = E.confidence_bounds(est_v, var_v, queries[0].confidence)
     err = E.error_ratio(est_v, lo, hi)
     return est_v, lo, hi, err

@@ -13,7 +13,10 @@ per-round tuple *budget*:
                  the *started set is always a prefix of the schedule*: a
                  chunk's inclusion in the sample can never depend on its
                  content.  This is the engine's inspection-paradox guarantee
-                 (paper §3/§4.2).
+                 (paper §3/§4.2).  Under residency="sharded" each chunk
+                 range has a queue of its own: the started set is a prefix
+                 of each range's committed order, and the ranges are the
+                 strata of a stratified sample.
     2. EXTRACT — each active worker extracts the next ``b`` tuples of its
                  chunk in the chunk's keyed Feistel order (paper §4.1's
                  in-memory shuffle), decodes them from raw bytes, evaluates
@@ -26,7 +29,8 @@ per-round tuple *budget*:
                  resource-aware policy between holistic-like (IO-bound) and
                  single-pass-like (CPU-bound) behaviour and drives the
                  exponential-decay budget rule of §5.4.
-    5. ESTIMATE— Eq. (1)/(3) over all started chunks; HAVING early-out.
+    5. ESTIMATE— Eq. (1)/(3) over all started chunks (per stratum, summed,
+                 under claim queues); HAVING early-out.
 
 Strategies (paper Fig. 5): ``chunk_level`` (C), ``holistic`` (H),
 ``single_pass`` (S), ``resource_aware`` (BI).  ``chunk_level`` additionally
@@ -129,7 +133,12 @@ class EngineConfig:
     # round a bounded (W, rows_max, rec) slab through
     # data/pipeline.SlabPrefetcher — device residency O(slab), host residency
     # O(cache), READ overlapped with compute.  Round-for-round estimates are
-    # identical (bit-exact on the ref backend).
+    # identical (bit-exact on the ref backend).  "sharded" is packed
+    # residency split over Q claim queues (the mesh's data axis size, or the
+    # single-device engines' ``queues``): queue q owns the contiguous chunk
+    # range [q·N/Q, (q+1)·N/Q), its workers claim only from it in its own
+    # committed random order, and on a mesh each device holds only its
+    # queue's range.  Estimates are stratified over the ranges.
     residency: str = "packed"
     slab_row_tile: int = 256     # streaming kernel's row-tile (VMEM bound)
     prefetch_lookahead: int = 8  # schedule chunks the reader thread runs ahead
@@ -154,7 +163,7 @@ class EngineConfig:
         assert self.strategy in STRATEGIES, self.strategy
         assert self.extract_backend in ("ref", "pallas", "pallas-interpret",
                                         "auto"), self.extract_backend
-        assert self.residency in ("packed", "stream"), self.residency
+        assert self.residency in ("packed", "stream", "sharded"), self.residency
         assert self.decoded_cache_bytes >= 0
         assert self.decoded_cache_bytes == 0 or self.residency == "stream", (
             "decoded_cache_bytes requires residency='stream' (the cache "
@@ -171,7 +180,8 @@ class EngineState(NamedTuple):
     offset: jnp.ndarray          # (N,) tuples extracted so far per chunk
     closed: jnp.ndarray          # (N,) bool — chunk closed for sampling
     acc_met: jnp.ndarray         # (N,) bool — local accuracy ε_j reached
-    head: jnp.ndarray            # () int32 — queue head over schedule
+    head: jnp.ndarray            # () int32 — schedule positions handed out
+                                 # (queue head; >= N: nothing left)
     cur: jnp.ndarray             # (P,) int32 — schedule position per worker (sharded under SPMD)
     budget: jnp.ndarray          # () f32 — current t_eval-analog budget
     decay: jnp.ndarray           # () f32 — §5.4 exponential-decay factor
@@ -215,6 +225,9 @@ class EngineState(NamedTuple):
     gys: jnp.ndarray             # (S, G, N) per-cell Σ x (group-masked)
     gyq: jnp.ndarray             # (S, G, N) per-cell Σ x²
     gps: jnp.ndarray             # (S, G, N) per-cell Σ p (base pred ∧ group)
+    # per-queue heads under residency="sharded" ((0,) otherwise): positions
+    # of queue q's schedule segment handed out; ``head`` is their sum
+    qhead: jnp.ndarray           # (Q,) int32
 
 
 class RoundReport(NamedTuple):
@@ -250,14 +263,19 @@ class _Collectives:
 
     ``gather_workers`` exposes every worker's flag in global worker order;
     ``merge`` sums contributions across devices; ``my_base`` is this device's
-    first global worker id.  The single-device instance is the identity, so
-    both modes run the *same* round body.
+    first global worker id; ``local_chunk`` maps a global chunk id to a row
+    of this device's raw-data block (the identity unless the block is this
+    device's ``chunks_per_device`` range of a sharded table).  The
+    single-device instance is the identity, so both modes run the *same*
+    round body.
     """
 
     def __init__(self, axis_name: Optional[str] = None,
-                 workers_per_device: Optional[int] = None):
+                 workers_per_device: Optional[int] = None,
+                 chunks_per_device: Optional[int] = None):
         self.axis_name = axis_name
         self.wpd = workers_per_device
+        self.cpd = chunks_per_device
 
     def gather_workers(self, x: jnp.ndarray) -> jnp.ndarray:
         if self.axis_name is None:
@@ -275,6 +293,14 @@ class _Collectives:
             return jnp.asarray(0, jnp.int32)
         return (jax.lax.axis_index(self.axis_name) * self.wpd).astype(jnp.int32)
 
+    def local_chunk(self, j: jnp.ndarray) -> jnp.ndarray:
+        if self.axis_name is None or self.cpd is None:
+            return j
+        base = (jax.lax.axis_index(self.axis_name) * self.cpd).astype(j.dtype)
+        # an idle worker's chunk id may lie in another device's range; it
+        # extracts nothing, but its gather must stay inside this block
+        return jnp.clip(j - base, 0, self.cpd - 1)
+
 
 class EngineProgram:
     """The jit-able round program, independent of host-side orchestration.
@@ -287,15 +313,32 @@ class EngineProgram:
                  config: EngineConfig, n_chunks: int, m_max: int,
                  chunk_sizes: np.ndarray,
                  schedule: Optional[np.ndarray] = None,
-                 max_slots: Optional[int] = None, confidence: float = 0.95):
+                 max_slots: Optional[int] = None, confidence: float = 0.95,
+                 queues: int = 1):
         self.codec = codec
         self.queries = list(queries)
         self.config = config
         self.n_chunks = int(n_chunks)
         self.m_max = int(m_max)
         self.max_slots = None if max_slots is None else int(max_slots)
+        # claim queues (residency="sharded"): queue q owns the contiguous
+        # chunk range of length N/Q at q·N/Q, and the schedule's segment
+        # [q·N/Q, (q+1)·N/Q) is its committed random order
+        self.sharded = config.residency == "sharded"
+        self.queues = int(queues)
+        assert self.queues >= 1 and (self.sharded or self.queues == 1), (
+            "claim queues > 1 need residency='sharded'")
+        if self.sharded:
+            assert self.n_chunks % self.queues == 0, (
+                f"{self.n_chunks} chunks do not split over {self.queues} "
+                "queues")
+            assert config.num_workers % self.queues == 0, (
+                f"{config.num_workers} workers do not split over "
+                f"{self.queues} queues")
         if schedule is None:
             schedule = random_chunk_order(config.seed, self.n_chunks)
+        if self.sharded:
+            schedule = queue_schedule(schedule, self.queues)
         self.schedule_np = np.asarray(schedule, np.int32)
         self.schedule = jnp.asarray(schedule, jnp.int32)
         self.seeds = chunk_seed(jnp.uint32(config.seed),
@@ -426,6 +469,7 @@ class EngineProgram:
             gys=jnp.zeros((q, self.group_cells, self.n_chunks), dtype),
             gyq=jnp.zeros((q, self.group_cells, self.n_chunks), dtype),
             gps=jnp.zeros((q, self.group_cells, self.n_chunks), dtype),
+            qhead=jnp.zeros((self.queues if self.sharded else 0,), jnp.int32),
         )
         if synopsis_seed is not None:
             stats = state.stats._replace(
@@ -476,16 +520,63 @@ class EngineProgram:
         new_head = head + int(np.sum(idle & (want < n)))
         return j, active, new_head
 
+    def open_heads(self, closed: np.ndarray, schedule: np.ndarray
+                   ) -> tuple[int, np.ndarray]:
+        """Host side: ``(head, qhead)`` rewound to each queue's first
+        not-closed schedule position (a top-up pass re-claims from there).
+        ``qhead`` is ``(Q,)`` under residency="sharded" and ``(0,)``
+        otherwise."""
+        done = np.asarray(closed)[np.asarray(schedule)].reshape(
+            self.queues, -1)
+        first = np.where(done.all(axis=1), done.shape[1],
+                         np.argmax(~done, axis=1))
+        qhead = first if self.sharded else first[:0]
+        return int(first.sum()), qhead.astype(np.int32)
+
     def _closed_prefix_mask(self, closed: jnp.ndarray,
                             schedule: jnp.ndarray) -> jnp.ndarray:
         """Reordering barrier (§3): chunk-level estimation may only use the
         *closed prefix* of the schedule — the chunks up to the first not-yet
-        -closed schedule position.  Returns the (N,) chunk mask."""
+        -closed schedule position; with claim queues, the union of each
+        queue's closed prefix of its own segment.  Returns the (N,) chunk
+        mask."""
         n = self.n_chunks
+        if self.sharded:
+            done = closed[schedule].reshape(self.queues, -1)
+            per = done.shape[1]
+            prefix_len = jnp.where(jnp.all(done, axis=1), per,
+                                   jnp.argmax(~done, axis=1))
+            inside = (jnp.arange(per)[None, :] < prefix_len[:, None])
+            return jnp.zeros((n,), bool).at[schedule].set(inside.reshape(-1))
         done_sched = closed[schedule]
         prefix_len = jnp.where(jnp.all(done_sched), n, jnp.argmax(~done_sched))
         return jnp.zeros((n,), bool).at[schedule].set(
             jnp.arange(n) < prefix_len)
+
+    def _claim_queues(self, state: EngineState, coll: _Collectives):
+        """CLAIM with one queue per chunk range: worker w claims from queue
+        w // (W/Q), by a prefix sum over the idle flags of that queue's
+        workers, the next positions of the queue's schedule segment.  On a
+        mesh with one queue per device a queue's workers are all local, so
+        no flags are gathered.  ``head >= N`` (nothing left anywhere) stops
+        every claim.  Returns ``(cur, claimed (Q,))``; the claims are
+        merged across devices with the round's deltas."""
+        nq = self.queues
+        per = self.n_chunks // nq
+        w_local = state.cur.shape[0]
+        ids = coll.my_base() + jnp.arange(w_local, dtype=jnp.int32)
+        queue = ids // (self.config.num_workers // nq)           # (W,)
+        mine = queue[:, None] == jnp.arange(nq, dtype=jnp.int32)[None, :]
+        idle = state.cur == IDLE
+        idle_q = (mine & idle[:, None]).astype(jnp.int32)        # (W, Q)
+        rank = jnp.sum((jnp.cumsum(idle_q, axis=0) - idle_q)
+                       * mine.astype(jnp.int32), axis=1)
+        want = state.qhead[queue] + rank
+        got = idle & (want < per) & (state.head < self.n_chunks)
+        cur = jnp.where(got, queue * per + want,
+                        jnp.where(idle, EXHAUSTED, state.cur))
+        claimed = jnp.sum((mine & got[:, None]).astype(jnp.int32), axis=0)
+        return cur, claimed
 
     def _round_tallies(self, colv: jnp.ndarray, pr: jnp.ndarray,
                        live: jnp.ndarray, rnd: jnp.ndarray,
@@ -560,19 +651,25 @@ class EngineProgram:
         sizes = state.stats.M
 
         # ---- 1. CLAIM: prefix-sum queue-head allocation -------------------
-        idle_local = state.cur == IDLE
-        idle_all = coll.gather_workers(idle_local)               # (P,) global order
-        ranks_all = jnp.cumsum(idle_all.astype(jnp.int32)) - idle_all.astype(jnp.int32)
-        w_local = state.cur.shape[0]
-        my_ids = coll.my_base() + jnp.arange(w_local, dtype=jnp.int32)
-        ranks = ranks_all[my_ids]
-        want_pos = state.head + ranks
-        got = idle_local & (want_pos < n)
-        cur = jnp.where(got, want_pos, jnp.where(idle_local, EXHAUSTED, state.cur))
-        head = state.head + jnp.sum(idle_all & (state.head + ranks_all < n))
+        if self.sharded:
+            cur, claimed = self._claim_queues(state, coll)
+        else:
+            idle_local = state.cur == IDLE
+            idle_all = coll.gather_workers(idle_local)           # (P,) global order
+            ranks_all = (jnp.cumsum(idle_all.astype(jnp.int32))
+                         - idle_all.astype(jnp.int32))
+            w_local = state.cur.shape[0]
+            my_ids = coll.my_base() + jnp.arange(w_local, dtype=jnp.int32)
+            ranks = ranks_all[my_ids]
+            want_pos = state.head + ranks
+            got = idle_local & (want_pos < n)
+            cur = jnp.where(got, want_pos,
+                            jnp.where(idle_local, EXHAUSTED, state.cur))
+            head = state.head + jnp.sum(idle_all & (state.head + ranks_all < n))
 
         active = cur >= 0
         j = state.schedule[jnp.clip(cur, 0, n - 1)]              # (W,) chunk ids
+        jd = coll.local_chunk(j)                                 # row of `data`
         mj = sizes[j]
         off = state.offset[j]                                    # permutation cursor
         m_before = state.scan_m[j]                               # scan tuples so far
@@ -663,7 +760,7 @@ class EngineProgram:
                     stats4 = res
             elif grouped:
                 stats4, cols, gstats4, tal_w = kernel_ops.slot_extract(
-                    data, j, idx, b_eff, coeffs, p_lo, p_hi, isc, gate_v,
+                    data, jd, idx, b_eff, coeffs, p_lo, p_hi, isc, gate_v,
                     weights=wts,
                     return_cols=cap > 0, backend=self.extract_backend,
                     gcol=slots.gcol, gval=slots.gval, gact=slots.gact,
@@ -677,7 +774,7 @@ class EngineProgram:
                 tal = jnp.sum(tal_w.astype(dtype), axis=0)       # (S, 3, H)
             else:
                 stats4, cols = kernel_ops.slot_extract(
-                    data, j, idx, b_eff, coeffs, p_lo, p_hi, isc, gate_v,
+                    data, jd, idx, b_eff, coeffs, p_lo, p_hi, isc, gate_v,
                     weights=wts,
                     return_cols=cap > 0, backend=self.extract_backend)
             sum_x = stats4[..., 1].astype(dtype).T               # (Q|S, W)
@@ -694,7 +791,7 @@ class EngineProgram:
                 if streaming:
                     raw = jax.vmap(lambda sw, ii: sw[ii])(data, idx)   # (W, B, rec)
                 else:
-                    raw = jax.vmap(lambda jj, ii: data[jj][ii])(j, idx)  # (W, B, rec)
+                    raw = jax.vmap(lambda jj, ii: data[jj][ii])(jd, idx)  # (W, B, rec)
                 cols = jax.vmap(self.codec.decode_ref)(raw)      # (W, B, C)
                 if decoded_mode == "mixed":
                     # decode_ref is row-elementwise, so decoded-slab gathers
@@ -763,7 +860,14 @@ class EngineProgram:
             deltas["dgps"] = jnp.zeros((q, gcells, n), dtype).at[:, :, j].add(
                 g_sum_p * af)
             deltas["gtal"] = tal
+        if self.sharded:
+            deltas["claims"] = claimed
         deltas = coll.merge(deltas)
+        if self.sharded:
+            qhead = state.qhead + deltas["claims"]
+            head = state.head + jnp.sum(deltas["claims"])
+        else:
+            qhead = state.qhead
         if slot_mode:
             # a slot only counts tuples extracted while it is active
             dm_q = slots.active.astype(jnp.int32)[:, None] * deltas["dmq"]
@@ -941,10 +1045,17 @@ class EngineProgram:
         # the survivors bit-for-bit, since the dropped columns are zero).
         alive = ~state.quarantined
         est_mask = est_mask & alive
-        n_eff = (jnp.asarray(stats.n_total, jnp.int32)
-                 - jnp.sum(state.quarantined.astype(jnp.int32)))
-        m_eff = (jnp.asarray(stats.m_total, jnp.int32)
-                 - jnp.sum(jnp.where(state.quarantined, sizes, 0)))
+        strata = self.queues
+        if strata > 1:
+            # stratified over the claim queues' chunk ranges: each range is
+            # a population of its own (quarantine shrinks only its range)
+            n_eff, m_eff = est.stratum_totals(sizes, state.quarantined,
+                                              strata)
+        else:
+            n_eff = (jnp.asarray(stats.n_total, jnp.int32)
+                     - jnp.sum(state.quarantined.astype(jnp.int32)))
+            m_eff = (jnp.asarray(stats.m_total, jnp.int32)
+                     - jnp.sum(jnp.where(state.quarantined, sizes, 0)))
         # (N,) masks broadcast over the leading query dim; (S, N) are per-slot
         stats_est = stats._replace(
             m=jnp.where(est_mask, stats.m, 0),
@@ -953,13 +1064,10 @@ class EngineProgram:
             psum=jnp.where(est_mask, stats.psum, 0),
             n_total=n_eff, m_total=m_eff)
 
-        sum_t = est.tau_hat(stats_est)
-        sum_v, _ = est.var_hat(stats_est)
-        cnt_t = est.count_tau_hat(stats_est)
-        cnt_v, _ = est.count_var_hat(stats_est)
         need_avg = slot_mode or any(qq.agg == "avg" for qq in self.queries)
-        if need_avg:
-            avg_t, avg_v, _ = est.avg_estimate(stats_est)
+        sum_t, sum_v, cnt_t, cnt_v, avg_t, avg_v = est.bilevel_estimates(
+            stats_est, strata,
+            aggs=("sum", "count", "avg") if need_avg else ("sum", "count"))
 
         if slot_mode:
             agg = slots.agg
@@ -991,11 +1099,8 @@ class EngineProgram:
                     ysq=jnp.where(gmask, gyq_new, 0),
                     psum=jnp.where(gmask, gps_new, 0),
                     n_total=n_eff, m_total=m_eff)
-                g_sum_t = est.tau_hat(gstats_est)
-                g_sum_v, _ = est.var_hat(gstats_est)
-                g_cnt_t = est.count_tau_hat(gstats_est)
-                g_cnt_v, _ = est.count_var_hat(gstats_est)
-                g_avg_t, g_avg_v, _ = est.avg_estimate(gstats_est)
+                (g_sum_t, g_sum_v, g_cnt_t, g_cnt_v, g_avg_t,
+                 g_avg_v) = est.bilevel_estimates(gstats_est, strata)
                 agg_b = agg[:, None]
                 g_est = jnp.where(agg_b == AGG_SUM, g_sum_t,
                                   jnp.where(agg_b == AGG_COUNT, g_cnt_t,
@@ -1072,7 +1177,7 @@ class EngineProgram:
             t_cpu=state.t_cpu + round_cpu, cpu_bound=cpu_bound,
             cached_m=state.cached_m, raw_touched=raw_touched, cache=cache,
             schedule=state.schedule, quarantined=state.quarantined,
-            gm=gm_new, gys=gys_new, gyq=gyq_new, gps=gps_new)
+            gm=gm_new, gys=gys_new, gyq=gyq_new, gps=gps_new, qhead=qhead)
         report = RoundReport(
             estimate=estimate, lo=lo, hi=hi, err=err, decided=decided,
             n_chunks=n_chunks_rep, m_tuples=m_tuples_rep,
@@ -1082,6 +1187,16 @@ class EngineProgram:
             g_est=g_est, g_lo=g_lo, g_hi=g_hi, g_err=g_err, g_n=g_n,
             g_tal=g_tal)
         return new_state, report
+
+
+def queue_schedule(order, queues: int) -> np.ndarray:
+    """A claim order split into ``queues`` segments: segment q holds the
+    chunks of range q (ids ``[q·N/Q, (q+1)·N/Q)``) in the order ``order``
+    gives them, so each queue keeps a uniformly random order of its own
+    range, and one queue keeps ``order`` itself."""
+    order = np.asarray(order, np.int32)
+    per = len(order) // queues
+    return order[np.argsort(order // per, kind="stable")]
 
 
 def budget_ladder(config: EngineConfig, m_max: int, b: float) -> int:
@@ -1291,11 +1406,18 @@ class _ResidencyMixin:
             self.pipeline.tracer = tracer
 
     def _init_residency(self, store, config: EngineConfig, slab_put=None,
-                        packed_put=None) -> np.ndarray:
+                        packed_put=None, shards_put=None) -> np.ndarray:
         """Set up ``self.packed``/``self.pipeline`` per the configured
         residency; returns the chunk-size vector.  ``slab_put``/``packed_put``
-        let the SPMD engines place buffers with mesh shardings."""
+        let the SPMD engines place buffers with mesh shardings, and
+        ``shards_put(store)`` places a sharded table range by range (one
+        device holds a sharded table whole, as packed)."""
         self.quarantine_log: list[int] = []
+        if config.residency == "sharded" and shards_put is not None:
+            self.pipeline = None
+            with self.tracer.span("ola.shard_place"):
+                self.packed = shards_put(store)
+            return store.chunk_sizes
         if config.residency == "stream":
             from repro.data.pipeline import SlabPrefetcher
 
@@ -1313,6 +1435,14 @@ class _ResidencyMixin:
         self.packed = (jnp.asarray(packed) if packed_put is None
                        else packed_put(packed))
         return sizes
+
+    @property
+    def resident_bytes(self) -> int:
+        """Raw-data bytes the busiest device holds: its chunk range of a
+        sharded table, else the whole padded table (0 when streaming)."""
+        if self.packed is None:
+            return 0
+        return max(s.data.nbytes for s in self.packed.addressable_shards)
 
     def round_data(self, state: EngineState) -> tuple[EngineState, object]:
         if self.pipeline is None:
@@ -1378,17 +1508,19 @@ class _ResidencyMixin:
 
 
 class OLAEngine(_ResidencyMixin):
-    """Host-facing single-process engine: owns device buffers + jitted rounds."""
+    """Host-facing single-process engine: owns device buffers + jitted rounds.
+    ``queues`` claim queues under residency="sharded": one device with Q
+    queues runs the program a Q-device mesh runs, round for round."""
 
     def __init__(self, store, queries: Sequence[Query], config: EngineConfig,
-                 schedule: Optional[np.ndarray] = None):
+                 schedule: Optional[np.ndarray] = None, queues: int = 1):
         self.store = store
         self.config = config
         sizes = self._init_residency(store, config)
         self.program = EngineProgram(
             codec=store.codec, queries=queries, config=config,
             n_chunks=store.num_chunks, m_max=store.max_chunk_tuples,
-            chunk_sizes=sizes, schedule=schedule)
+            chunk_sizes=sizes, schedule=schedule, queues=queues)
         speeds = config.worker_speed or (1.0,) * config.num_workers
         assert len(speeds) == config.num_workers
         self.speeds = jnp.asarray(speeds, jnp.float32)
@@ -1447,19 +1579,20 @@ class SlotOLAEngine(_ResidencyMixin):
     disturbance to the other slots' statistics.  The workload server
     (``repro.serve.ola_server.OLAWorkloadServer``) owns admission policy,
     synopsis seeding, and top-up passes; this class owns device buffers and
-    the jitted step.
+    the jitted step.  ``queues`` as in :class:`OLAEngine`.
     """
 
     def __init__(self, store, max_slots: int, config: EngineConfig,
                  schedule: Optional[np.ndarray] = None,
-                 confidence: float = 0.95):
+                 confidence: float = 0.95, queues: int = 1):
         self.store = store
         self.config = config
         sizes = self._init_residency(store, config)
         self.program = EngineProgram(
             codec=store.codec, config=config, n_chunks=store.num_chunks,
             m_max=store.max_chunk_tuples, chunk_sizes=sizes,
-            schedule=schedule, max_slots=max_slots, confidence=confidence)
+            schedule=schedule, max_slots=max_slots, confidence=confidence,
+            queues=queues)
         speeds = config.worker_speed or (1.0,) * config.num_workers
         assert len(speeds) == config.num_workers
         self.speeds = jnp.asarray(speeds, jnp.float32)

@@ -3,16 +3,21 @@
 The worker axis is sharded over the mesh ``data`` axis (DESIGN.md §3:
 EXTRACT threads → devices); every other piece of engine state is replicated
 and advanced by psum-merged deltas, so all devices hold identical state —
-the SPMD analogue of the paper's shared memory.  The raw chunk buffer is
-replicated too, mirroring the paper's "all threads see the file" model; a
-host-sharded store with a per-host queue is the scale-out extension
-(distributed/fault.py handles chunk reassignment on host loss).
+the SPMD analogue of the paper's shared memory.  Under packed residency the
+raw chunk buffer is replicated too, mirroring the paper's "all threads see
+the file" model.  Under ``residency="sharded"`` each device holds only its
+contiguous range of chunks, placed range by range, and its workers claim
+from that range's own queue (one queue per device); state stays indexed by
+global chunk id and estimates are stratified over the ranges
+(``EngineConfig.residency``).
 
 Semantics are *identical* to the single-device engine with
-``num_workers = devices × workers_per_device`` — property-tested in
-tests/test_engine_spmd.py.  The claim step's prefix-sum sees the all-gathered
-idle flags in global worker order, so chunk hand-out order is deterministic
-and independent of device count.
+``num_workers = devices × workers_per_device`` (and, sharded, with
+``queues = devices``) — property-tested in tests/test_engine_spmd.py and
+tests/test_engine_sharded.py.  The claim step's prefix-sum sees the
+all-gathered idle flags in global worker order (a queue's own workers,
+sharded), so chunk hand-out order is deterministic and independent of
+device count.
 """
 
 from __future__ import annotations
@@ -35,6 +40,8 @@ from repro.core.engine import (
 )
 from repro.core.estimators import BiLevelStats
 from repro.core.queries import Query, SlotTable
+from repro.kernels.slot_extract import GATHER_ROWS
+from repro.obs.trace import NULL_TRACER
 
 
 def engine_state_specs() -> EngineState:
@@ -51,7 +58,8 @@ def engine_state_specs() -> EngineState:
         head=rep, cur=P("data"), budget=rep, decay=rep, calib_sum=rep,
         calib_cnt=rep, first_est=rep, stopped=rep, round=rep, t_io=rep,
         t_cpu=rep, cpu_bound=rep, cached_m=rep, raw_touched=rep, cache=rep,
-        schedule=rep, quarantined=rep, gm=rep, gys=rep, gyq=rep, gps=rep)
+        schedule=rep, quarantined=rep, gm=rep, gys=rep, gyq=rep, gps=rep,
+        qhead=rep)
 
 
 def report_specs() -> RoundReport:
@@ -66,13 +74,16 @@ def slot_table_specs() -> SlotTable:
 
 class _SPMDEngineBase(_ResidencyMixin):
     """Shared mesh plumbing for the SPMD engines: worker split over the
-    ``data`` axis, replicated chunk buffer (packed residency) or a
-    worker-sharded per-round slab (stream residency), sharded per-worker
-    speeds, state sharding, the per-budget compile cache, and the t_eval
-    ladder."""
+    ``data`` axis, replicated chunk buffer (packed residency), chunk ranges
+    split over the axis (sharded residency) or a worker-sharded per-round
+    slab (stream residency), sharded per-worker speeds, state sharding, the
+    per-budget compile cache, and the t_eval ladder.  ``tracer`` receives
+    the ``ola.shard_place`` span around a sharded table's placement."""
 
-    def __init__(self, store, config: EngineConfig, mesh: Mesh):
+    def __init__(self, store, config: EngineConfig, mesh: Mesh,
+                 tracer=NULL_TRACER):
         self.store = store
+        self.tracer = tracer
         # The engine runs on an Auto-typed view of the caller's mesh: the
         # serving host writes single rows of the sharded state eagerly
         # (admission seeds, claim reorders, quarantine), and under the
@@ -86,6 +97,8 @@ class _SPMDEngineBase(_ResidencyMixin):
             f"data axis size {self.n_dev}")
         self.wpd = config.num_workers // self.n_dev
         self.config = config
+        self.sharded = config.residency == "sharded"
+        self.queues = self.n_dev if self.sharded else 1
         # slab rows are per-worker, so under stream residency the slab shards
         # over the mesh's worker axis — each device receives only its
         # workers' chunks; the packed view stays replicated
@@ -94,13 +107,41 @@ class _SPMDEngineBase(_ResidencyMixin):
             slab_put=lambda a: jax.device_put(
                 a, NamedSharding(mesh, P("data"))),
             packed_put=lambda a: jax.device_put(
-                a, NamedSharding(mesh, P())))
+                a, NamedSharding(mesh, P())),
+            shards_put=self._place_shards)
         self.m_max = int(store.max_chunk_tuples)
         speeds = config.worker_speed or (1.0,) * config.num_workers
         assert len(speeds) == config.num_workers
         self.speeds = jax.device_put(np.asarray(speeds, np.float32),
                                      NamedSharding(mesh, P("data")))
         self._round_fns: dict[tuple, callable] = {}
+
+    def _place_shards(self, store) -> jax.Array:
+        """The packed ``(N, M_pad, rec)`` view split over the ``data`` axis,
+        built and copied one device's chunk range at a time, so the host
+        never holds a second copy of the whole table."""
+        n = store.num_chunks
+        assert n % self.n_dev == 0, (
+            f"{n} chunks do not split over {self.n_dev} devices")
+        rows = -(-store.max_chunk_tuples // GATHER_ROWS) * GATHER_ROWS
+        shape = (n, rows, store.codec.record_bytes)
+        sharding = NamedSharding(self.mesh, P("data"))
+        parts = []
+        for dev, index in sharding.addressable_devices_indices_map(
+                shape).items():
+            lo, hi, _ = index[0].indices(n)
+            block, _ = store.packed_device_view(row_multiple=GATHER_ROWS,
+                                                chunks=range(lo, hi))
+            parts.append(jax.device_put(block, dev).block_until_ready())
+            del block
+        return jax.make_array_from_single_device_arrays(shape, sharding,
+                                                        parts)
+
+    def _collectives(self) -> _Collectives:
+        return _Collectives(
+            axis_name="data", workers_per_device=self.wpd,
+            chunks_per_device=(self.store.num_chunks // self.n_dev
+                               if self.sharded else None))
 
     def _put_state(self, state: EngineState) -> EngineState:
         shardings = jax.tree.map(lambda spec: NamedSharding(self.mesh, spec),
@@ -115,11 +156,14 @@ class _SPMDEngineBase(_ResidencyMixin):
         replicated in packed residency and worker-sharded in stream
         residency (slab rows follow their workers); a decoded round's data
         is the ``(raw, dec, is_decoded)`` triple — every leaf is per-worker,
-        so all three shard over the mesh worker axis."""
+        so all three shard over the mesh worker axis.  A sharded table
+        splits over the axis by chunk range."""
         specs = engine_state_specs()
         if self.config.residency == "stream":
             data_spec = ((P("data"), P("data"), P("data"))
                          if decoded_mode != "none" else P("data"))
+        elif self.sharded:
+            data_spec = P("data")
         else:
             data_spec = P()
         sm = jax.shard_map(step, mesh=self.mesh,
@@ -137,12 +181,14 @@ class SPMDEngine(_SPMDEngineBase):
     """Multi-device OLA engine over a mesh with a ``data`` axis."""
 
     def __init__(self, store, queries: Sequence[Query], config: EngineConfig,
-                 mesh: Mesh, schedule: Optional[np.ndarray] = None):
-        super().__init__(store, config, mesh)
+                 mesh: Mesh, schedule: Optional[np.ndarray] = None,
+                 tracer=NULL_TRACER):
+        super().__init__(store, config, mesh, tracer=tracer)
         self.program = EngineProgram(
             codec=store.codec, queries=queries, config=config,
             n_chunks=store.num_chunks, m_max=store.max_chunk_tuples,
-            chunk_sizes=self.chunk_sizes, schedule=schedule)
+            chunk_sizes=self.chunk_sizes, schedule=schedule,
+            queues=self.queues)
 
     @property
     def queries(self):
@@ -154,7 +200,7 @@ class SPMDEngine(_SPMDEngineBase):
     def round_fn(self, b_static: int, decoded_mode: str = "none"):
         key = (b_static, decoded_mode)
         if key not in self._round_fns:
-            coll = _Collectives(axis_name="data", workers_per_device=self.wpd)
+            coll = self._collectives()
 
             def step(state, packed, speeds):
                 return self.program.round_body(state, packed, speeds,
@@ -200,12 +246,13 @@ class SlotSPMDEngine(_SPMDEngineBase):
 
     def __init__(self, store, max_slots: int, config: EngineConfig,
                  mesh: Mesh, schedule: Optional[np.ndarray] = None,
-                 confidence: float = 0.95):
-        super().__init__(store, config, mesh)
+                 confidence: float = 0.95, tracer=NULL_TRACER):
+        super().__init__(store, config, mesh, tracer=tracer)
         self.program = EngineProgram(
             codec=store.codec, config=config, n_chunks=store.num_chunks,
             m_max=store.max_chunk_tuples, chunk_sizes=self.chunk_sizes,
-            schedule=schedule, max_slots=max_slots, confidence=confidence)
+            schedule=schedule, max_slots=max_slots, confidence=confidence,
+            queues=self.queues)
 
     @property
     def max_slots(self) -> int:
@@ -217,7 +264,7 @@ class SlotSPMDEngine(_SPMDEngineBase):
     def round_fn(self, b_static: int, decoded_mode: str = "none"):
         key = (b_static, decoded_mode)
         if key not in self._round_fns:
-            coll = _Collectives(axis_name="data", workers_per_device=self.wpd)
+            coll = self._collectives()
 
             def step(state, table, packed, speeds):
                 return self.program.round_body(state, packed, speeds,

@@ -36,10 +36,12 @@ the statistical tests).  Degenerate cases follow the paper's semantics:
 
 from __future__ import annotations
 
+import statistics
 from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.scipy.special import ndtri
 
 
@@ -211,19 +213,157 @@ def avg_estimate(stats: BiLevelStats) -> tuple[jnp.ndarray, jnp.ndarray, jnp.nda
     Σ x_i·p_i equals Σ x_i because x_i is already predicate-masked.
     Returns ``(estimate, variance, valid)``.
     """
-    dtype = stats.ysum.dtype
     tx = tau_hat(stats)
     tp = count_tau_hat(stats)
     var_x, vx_ok = var_hat(stats)
     var_p, vp_ok = count_var_hat(stats)
     cov_xp, cv_ok = _cov_hat(stats, stats.ysum, stats.psum, cross=stats.ysum)
+    r, var_r = _ratio(tx, tp, var_x, var_p, cov_xp)
+    return r, var_r, vx_ok & vp_ok & cv_ok
+
+
+def _ratio(tx, tp, var_x, var_p, cov_xp):
+    """R̂ = τ̂_x / τ̂_p and its delta-method variance (see avg_estimate)."""
+    dtype = tx.dtype
     tp_safe = jnp.where(jnp.abs(tp) > 0, tp, jnp.ones_like(tp))
     r = tx / tp_safe
     var_r = (var_x + r * r * var_p - 2.0 * r * cov_xp) / (tp_safe * tp_safe)
     # Delta-method variances can go slightly negative near m_j == M_j; clamp.
     var_r = jnp.maximum(var_r, jnp.zeros_like(var_r))
     var_r = jnp.where(jnp.abs(tp) > 0, var_r, jnp.asarray(jnp.inf, dtype))
-    return r, var_r, vx_ok & vp_ok & cv_ok
+    return r, var_r
+
+
+# ---------------------------------------------------------------------------
+# Stratified bi-level sampling: the chunk axis split into contiguous strata
+# (the per-shard claim queues of a sharded table), each sampled in its own
+# committed random order.
+# ---------------------------------------------------------------------------
+
+def stratum_totals(chunk_sizes, dropped, strata: int):
+    """Per-stratum population ``(N_h, M_h)``, each ``(strata,)``: the chunk
+    count and tuple total of each contiguous chunk range, less the chunks
+    ``dropped`` (quarantined) from it."""
+    sizes = jnp.asarray(chunk_sizes).reshape(strata, -1)
+    gone = jnp.asarray(dropped).reshape(strata, -1)
+    n_h = sizes.shape[1] - jnp.sum(gone.astype(jnp.int32), axis=-1)
+    m_h = jnp.sum(jnp.where(gone, 0, sizes), axis=-1)
+    return n_h, m_h
+
+
+def split_strata(stats: BiLevelStats, strata: int) -> BiLevelStats:
+    """View of ``stats`` with the chunk axis ``(..., N)`` split into
+    ``(..., strata, N / strata)`` contiguous strata.  ``n_total`` and
+    ``m_total`` must already be per stratum (:func:`stratum_totals`)."""
+    def split(x):
+        x = jnp.asarray(x)
+        return x.reshape(x.shape[:-1] + (strata, x.shape[-1] // strata))
+
+    return stats._replace(M=split(stats.M), m=split(stats.m),
+                          ysum=split(stats.ysum), ysq=split(stats.ysq),
+                          psum=split(stats.psum))
+
+
+def bilevel_estimates(stats: BiLevelStats, strata: int = 1,
+                      aggs=("sum", "count", "avg")) -> tuple:
+    """``(sum_t, sum_v, cnt_t, cnt_v, avg_t, avg_v)``: SUM, COUNT and
+    AVERAGE estimates with their variances, each pair ``None`` unless
+    ``aggs`` names it.
+
+    With one stratum these are Eq. (1) and (3) over the whole chunk space.
+    With ``strata > 1`` the chunk axis is split as in :func:`split_strata`:
+    Eq. (1) and (3) apply within each stratum, and the stratum estimates and
+    variances are summed (stratified sampling); AVERAGE is the combined
+    ratio of the summed SUM and COUNT estimates, with the summed
+    covariance.  A stratum with fewer than two sampled chunks leaves every
+    variance infinite.
+    """
+    sum_t = sum_v = cnt_t = cnt_v = avg_t = avg_v = None
+    if strata == 1:
+        if "sum" in aggs:
+            sum_t, (sum_v, _) = tau_hat(stats), var_hat(stats)
+        if "count" in aggs:
+            cnt_t, (cnt_v, _) = count_tau_hat(stats), count_var_hat(stats)
+        if "avg" in aggs:
+            avg_t, avg_v, _ = avg_estimate(stats)
+        return sum_t, sum_v, cnt_t, cnt_v, avg_t, avg_v
+    st = split_strata(stats, strata)
+    if aggs:
+        sum_t = jnp.sum(tau_hat(st), axis=-1)
+        sum_v = jnp.sum(var_hat(st)[0], axis=-1)
+        cnt_t = jnp.sum(count_tau_hat(st), axis=-1)
+        cnt_v = jnp.sum(count_var_hat(st)[0], axis=-1)
+    if "avg" in aggs:
+        cov = jnp.sum(_cov_hat(st, st.ysum, st.psum, cross=st.ysum)[0],
+                      axis=-1)
+        avg_t, avg_v = _ratio(sum_t, cnt_t, sum_v, cnt_v, cov)
+        # inf - inf in the delta method would read as a NaN variance
+        known = jnp.isfinite(sum_v) & jnp.isfinite(cnt_v) & jnp.isfinite(cov)
+        avg_v = jnp.where(known, avg_v, jnp.asarray(jnp.inf, avg_v.dtype))
+    return sum_t, sum_v, cnt_t, cnt_v, avg_t, avg_v
+
+
+def stratified_row_answer(agg: str, sizes, n_h, m, ysum, ysq, psum,
+                          confidence: float = 0.95) -> tuple:
+    """``(estimate, lo, hi, err)`` of one query's statistics row,
+    stratified over ``len(n_h)`` contiguous chunk ranges, computed on the
+    host in float64 numpy: :func:`bilevel_estimates` (``strata > 1``) and
+    :func:`confidence_bounds` for a single row, without a device op.
+    ``sizes`` are the chunk sizes M_j, ``n_h`` each stratum's chunk
+    population (:func:`stratum_totals`), ``m``/``ysum``/``ysq``/``psum``
+    the ``(N,)`` row."""
+    q = len(n_h)
+
+    def f(x):
+        return np.asarray(x, np.float64).reshape(q, -1)
+
+    big_m, m = f(sizes), f(m)
+    big_n = np.asarray(n_h, np.float64)
+    ins = m > 0
+    n = ins.sum(axis=1).astype(np.float64)
+    n1 = np.maximum(n, 1.0)
+    m1 = np.maximum(m, 1.0)
+    scale = np.where(ins, big_m / m1, 0.0)                   # M_j / m_j
+    fpc = (big_m - m1) / np.maximum(m1 - 1.0, 1.0)
+    # m_j == 1 on a multi-tuple chunk: the within term is not estimable
+    within_ok = ins & ~((m == 1) & (big_m > 1))
+
+    def tau(a):
+        return float(np.sum(big_n / n1 * np.sum(scale * f(a), axis=1)))
+
+    def cov(a, b, cross):
+        a, b, cross = f(a), f(b), f(cross)
+        ahat, bhat = scale * a, scale * b
+        abar = ahat.sum(axis=1, keepdims=True) / n1[:, None]
+        bbar = bhat.sum(axis=1, keepdims=True) / n1[:, None]
+        ss = np.sum(ins * (ahat - abar) * (bhat - bbar), axis=1)
+        between = np.where(
+            n > 1, big_n / n1 * (big_n - n) / np.maximum(n - 1.0, 1.0) * ss,
+            np.inf)
+        # a census of the stratum has no between-chunk variance
+        between = np.where(n == big_n, np.nan_to_num(between, posinf=0.0),
+                           between)
+        within = np.where(within_ok, scale * fpc * (cross - a * b / m1), 0.0)
+        return float(np.sum(between + big_n / n1 * within.sum(axis=1)))
+
+    with np.errstate(all="ignore"):
+        if agg == "sum":
+            e, v = tau(ysum), cov(ysum, ysum, ysq)
+        elif agg == "count":
+            e, v = tau(psum), cov(psum, psum, psum)
+        else:
+            tx, tp = tau(ysum), tau(psum)
+            vx, vp = cov(ysum, ysum, ysq), cov(psum, psum, psum)
+            cxp = cov(ysum, psum, ysum)
+            e = tx / tp if tp != 0 else tx
+            v = max((vx + e * e * vp - 2.0 * e * cxp) / (tp * tp), 0.0)
+            if tp == 0 or not np.isfinite([vx, vp, cxp]).all():
+                v = np.inf
+        half = (statistics.NormalDist().inv_cdf((1.0 + confidence) / 2.0)
+                * np.sqrt(max(v, 0.0)))
+        lo, hi = e - half, e + half
+        err = (hi - lo) / max(abs(e), 1e-30)
+    return e, lo, hi, err
 
 
 def confidence_bounds(estimate, variance, confidence: float = 0.95):
@@ -271,6 +411,18 @@ def having_decision(lo, hi, op: str, threshold) -> jnp.ndarray:
     if op not in HAVING_OP_CODES:
         raise ValueError(f"unsupported HAVING op: {op}")
     return having_decision_coded(lo, hi, HAVING_OP_CODES[op], threshold)
+
+
+def having_verdict(lo, hi, op: str, threshold) -> int:
+    """:func:`having_decision` of one interval on the host, in float32
+    numpy: the serving host decides seed-only and per-cell verdicts without
+    running (or compiling) a device op."""
+    if op not in HAVING_OP_CODES:
+        raise ValueError(f"unsupported HAVING op: {op}")
+    lo, hi, t = np.float32(lo), np.float32(hi), np.float32(threshold)
+    true_ = {"<": hi < t, "<=": hi <= t, ">": lo > t, ">=": lo >= t}[op]
+    false_ = lo > t if op in ("<", "<=") else hi < t
+    return 1 if true_ else (0 if false_ else -1)
 
 
 # ---------------------------------------------------------------------------

@@ -17,7 +17,9 @@ Two device-facing residency modes (selected by ``EngineConfig.residency``):
 
 * ``"packed"`` — :meth:`packed_device_view`: a padded
   ``(N, max_record_count, record_bytes)`` uint8 tensor for the jitted
-  engine.  O(dataset) device memory; right for stores that fit.
+  engine.  O(dataset) device memory; right for stores that fit.  Under
+  ``"sharded"`` residency the same view is built one chunk range per
+  device, so each device (and the host, at a time) holds only its range.
 * ``"stream"`` — the engine pulls bounded per-round ``(W, rows_max, rec)``
   slabs through :class:`repro.data.pipeline.SlabPrefetcher`: chunks are read
   (and, when disk-backed, evicted) on the fly by a background reader thread,
@@ -223,20 +225,23 @@ class ChunkStore:
         if self._chunks[j] is None:
             self._chunks[j] = self.chunk_bytes(j)
 
-    def packed_device_view(self, row_multiple: int = 1
+    def packed_device_view(self, row_multiple: int = 1, chunks=None
                            ) -> tuple[np.ndarray, np.ndarray]:
         """Padded ``(N, M_pad, record_bytes)`` uint8 + ``(N,)`` sizes, where
-        ``M_pad`` is ``M_max`` rounded up to ``row_multiple``.
+        ``M_pad`` is ``M_max`` rounded up to ``row_multiple``.  ``chunks``
+        (a range of chunk ids) packs only those, as ``(len(chunks), M_pad,
+        record_bytes)``: one device's share of a sharded table.
 
         Padding rows are zero; the engine masks by ``M_j`` so they are never
         included in estimation.
         """
-        n, rb = self.num_chunks, self.codec.record_bytes
+        rb = self.codec.record_bytes
+        chunks = range(self.num_chunks) if chunks is None else chunks
         mx = -(-self.max_chunk_tuples // row_multiple) * row_multiple
-        out = np.zeros((n, mx, rb), np.uint8)
-        for j in range(n):
+        out = np.zeros((len(chunks), mx, rb), np.uint8)
+        for i, j in enumerate(chunks):
             raw = self.chunk_bytes(j)
-            out[j, : raw.shape[0]] = raw
+            out[i, : raw.shape[0]] = raw
             # a disk-backed store must not end up resident twice (raw chunks
             # cached by an earlier pass + this packed copy)
             self.evict(j)

@@ -88,19 +88,41 @@ def variance_claim_order(state, chunk_sizes: np.ndarray,
     """New ``(N,)`` schedule with the unclaimed tail variance-ordered, or
     ``None`` when the order is already optimal / there is nothing to
     reorder.  Positions ``< state.head`` (claimed or done — every worker's
-    held position is below the head) are never moved.  ``slot_need``
+    held position is below the head) are never moved; with claim queues
+    (``state.qhead``), each queue's segment keeps its claimed prefix and
+    reorders its own tail.  ``slot_need``
     switches the per-chunk key to the ε-distance-weighted aggregate (see
     :func:`slot_chunk_variances`)."""
     schedule = np.asarray(state.schedule)
     n = len(schedule)
-    head = int(state.head)
-    if head >= n - 1:
+    qhead = np.asarray(getattr(state, "qhead", ()), np.int64)
+    if qhead.size:
+        # claim queues: each queue's segment reorders its own tail, so a
+        # chunk never moves into another queue
+        per = n // qhead.size
+        tails = [(q * per + int(h), (q + 1) * per)
+                 for q, h in enumerate(qhead)]
+    else:
+        tails = [(int(state.head), n)]
+    if all(lo >= hi - 1 for lo, hi in tails):
         return None
-    tail = schedule[head:]
     scan_m = np.asarray(state.scan_m)
     closed = np.asarray(state.closed)
     sizes = np.asarray(chunk_sizes)
     v = slot_chunk_variances(state, active, slot_need)
+    out, moved = schedule.copy(), False
+    for lo, hi in tails:
+        new_tail = _tail_order(schedule[lo:hi], v, closed, scan_m, sizes)
+        if new_tail is not None:
+            out[lo:hi] = new_tail
+            moved = True
+    return out.astype(np.int32) if moved else None
+
+
+def _tail_order(tail, v, closed, scan_m, sizes) -> Optional[np.ndarray]:
+    """One unclaimed tail in the three bands, or ``None`` if unchanged."""
+    if len(tail) <= 1:
+        return None
     dead = closed[tail] | (scan_m[tail] >= sizes[tail])
     started = scan_m[tail] > 0
     band = np.where(dead, 2, np.where(started, 1, 0))
@@ -115,6 +137,4 @@ def variance_claim_order(state, chunk_sizes: np.ndarray,
     new_tail = tail[order]
     if np.array_equal(new_tail, tail):
         return None
-    out = schedule.copy()
-    out[head:] = new_tail
-    return out.astype(np.int32)
+    return new_tail

@@ -457,6 +457,10 @@ class OLAWorkloadServer:
             config = dataclasses.replace(config, cache_cap=cap)
         self.store = store
         self.config = config
+        # observability: span tracer (profiler annotations by default),
+        # made before the engine so that a sharded placement's span lands
+        # in it; the metrics registry follows below
+        self.tracer = tracer if tracer is not None else ProfilerTracer()
         if engine is not None:
             self.engine = engine
         elif mesh is not None:
@@ -464,7 +468,8 @@ class OLAWorkloadServer:
 
             self.engine = SlotSPMDEngine(store, max_slots, config, mesh,
                                          schedule=schedule,
-                                         confidence=confidence)
+                                         confidence=confidence,
+                                         tracer=self.tracer)
         else:
             self.engine = SlotOLAEngine(store, max_slots, config,
                                         schedule=schedule,
@@ -531,14 +536,12 @@ class OLAWorkloadServer:
         self._quarantine_count = 0      # chunks quarantined so far
         self._eff_chunks = int(store.num_chunks)
         self._eff_tuples = int(store.num_tuples)
+        self._alive = np.ones(store.num_chunks, bool)
         self._eff_bytes = (float(np.asarray(store.chunk_sizes).sum())
                            * store.codec.record_bytes)
         self._slot_retries0 = np.zeros(max_slots, np.int64)
         self._scan_rate = scan_tuples_per_s(store, self.config,
                                             rates=self.rates)
-        # observability: span tracer (profiler annotations by default) and
-        # the metrics registry every scattered counter surfaces through
-        self.tracer = tracer if tracer is not None else ProfilerTracer()
         set_tracer = getattr(self.engine, "set_tracer", None)
         if set_tracer is not None:
             set_tracer(self.tracer)
@@ -590,6 +593,14 @@ class OLAWorkloadServer:
             self.rollup.bind_metrics(reg)
         if self.scheduler is not None:
             self.scheduler.bind_metrics(reg)
+        if self.config.residency == "sharded":
+            reg.gauge("server_shard_resident_bytes",
+                      help="raw table bytes resident on each device",
+                      fn=lambda: self.engine.resident_bytes)
+            reg.gauge("server_shard_claim_spread",
+                      help="most minus fewest chunks claimed among the "
+                      "claim queues in this pass",
+                      fn=self._claim_spread)
         injected = getattr(self.store, "injected", None)
         if isinstance(injected, dict):
             for kind in sorted(injected):
@@ -597,6 +608,10 @@ class OLAWorkloadServer:
                           help="FaultInjector events by kind",
                           labels={"kind": kind},
                           fn=(lambda k=kind: self.store.injected.get(k, 0)))
+
+    def _claim_spread(self) -> int:
+        qhead = np.asarray(self.state.qhead)
+        return int(qhead.max() - qhead.min()) if qhead.size else 0
 
     def metrics_snapshot(self) -> dict:
         """Public JSON-able observability snapshot: every registry
@@ -693,7 +708,7 @@ class OLAWorkloadServer:
         qn = np.asarray(self.state.quarantined)
         self._quarantine_count = int(qn.sum())
         sizes = np.asarray(self.store.chunk_sizes)
-        alive = ~qn
+        alive = self._alive = ~qn
         self._eff_chunks = int(alive.sum())
         self._eff_tuples = int(sizes[alive].sum())
         self._eff_bytes = (float(sizes[alive].sum())
@@ -984,8 +999,8 @@ class OLAWorkloadServer:
             q = wq.query
             decision = -1
             if q.having is not None and m > 0:
-                decision = int(est.having_decision(lo, hi, q.having.op,
-                                                   q.having.threshold))
+                decision = est.having_verdict(lo, hi, q.having.op,
+                                              q.having.threshold)
             out = (m, est_v, lo, hi, err, decision)
             self._rollup_cache[wq.qid] = out
         return out
@@ -1158,18 +1173,35 @@ class OLAWorkloadServer:
         seed = self._mask_quarantined_seed(seed)
         if seed is None or int(seed["m"].sum()) == 0:
             return 0, float("nan"), float("nan"), float("nan"), float("inf")
-        # population substitution: after quarantine the estimator's N/M are
-        # the surviving totals (the same rescale the jitted round applies)
-        stats_row = self.state.stats._replace(
-            m=jnp.asarray(seed["m"], jnp.int32),
-            ysum=jnp.asarray(seed["ysum"])[None],
-            ysq=jnp.asarray(seed["ysq"])[None],
-            psum=jnp.asarray(seed["psum"])[None],
-            n_total=self._eff_chunks, m_total=self._eff_tuples)
-        est_v, lo, hi, err = _answer_from_stats([query], stats_row)
+        est_v, lo, hi, err = self._row_answer(
+            query, seed["m"], seed["ysum"], seed["ysq"], seed["psum"])
         return (int(seed["m"].sum()), float(np.asarray(est_v)[0]),
                 float(np.asarray(lo)[0]), float(np.asarray(hi)[0]),
                 float(np.asarray(err)[0]))
+
+    def _row_answer(self, query: Query, m, ysum, ysq, psum) -> tuple:
+        """``(estimate, lo, hi, err)``, each of shape (1,), of ``query``
+        from one host statistics row, over the surviving population (after
+        quarantine the estimator's N/M are the surviving totals, the same
+        rescale the jitted round applies).  Stratified over the claim
+        queues as the round's estimates are, and then computed on the host:
+        eager ops on a mesh's replicated state would each run on every
+        device."""
+        strata = self.engine.program.queues
+        if strata > 1:
+            row = self._mask_quarantined_seed(
+                dict(m=m, ysum=ysum, ysq=ysq, psum=psum))
+            n_h = self._alive.reshape(strata, -1).sum(axis=1)
+            return tuple(np.asarray([x]) for x in est.stratified_row_answer(
+                query.agg, self.store.chunk_sizes, n_h, row["m"],
+                row["ysum"], row["ysq"], row["psum"], query.confidence))
+        dtype = self.state.stats.ysum.dtype
+        stats_row = self.state.stats._replace(
+            m=jnp.asarray(m, jnp.int32), ysum=jnp.asarray(ysum, dtype)[None],
+            ysq=jnp.asarray(ysq, dtype)[None],
+            psum=jnp.asarray(psum, dtype)[None],
+            n_total=self._eff_chunks, m_total=self._eff_tuples)
+        return _answer_from_stats([query], stats_row)
 
     def _shed(self, wq: WorkloadQuery) -> None:
         """Answer a shed query immediately from the synopsis (flagged
@@ -1184,8 +1216,8 @@ class OLAWorkloadServer:
         else:
             decision = -1
             if q.having is not None:
-                decision = int(est.having_decision(lo, hi, q.having.op,
-                                                   q.having.threshold))
+                decision = est.having_verdict(lo, hi, q.having.op,
+                                              q.having.threshold)
             unserved, from_syn = False, True
         latency = now - wq.arrival_t
         slo_met = None
@@ -1249,7 +1281,7 @@ class OLAWorkloadServer:
             # provably needed.
             if seed is not None:
                 with tr.span("ola.seed_retire"):
-                    self._try_retire_from_seed(s, wq)
+                    self._try_retire_from_seed(s, wq, seed)
 
     def _admission_seed(self, wq: WorkloadQuery) -> Optional[dict]:
         """The statistics row a newcomer's slot starts from, or None."""
@@ -1336,24 +1368,22 @@ class OLAWorkloadServer:
             if not wq.explain.admission_reason:
                 wq.explain.admission_reason = "fifo: free slot"
 
-    def _try_retire_from_seed(self, s: int, wq: WorkloadQuery) -> bool:
+    def _try_retire_from_seed(self, s: int, wq: WorkloadQuery,
+                              seed: dict) -> bool:
         q = wq.query
         if q.group_by is not None:
             # the seed meets the scalar target at best; the per-group cells
             # only fill from scan rounds, so a grouped query always scans
             return False
-        stats_row = self.state.stats._replace(
-            m=self.state.stats.m[s], ysum=self.state.stats.ysum[s][None],
-            ysq=self.state.stats.ysq[s][None],
-            psum=self.state.stats.psum[s][None],
-            n_total=self._eff_chunks, m_total=self._eff_tuples)
-        est_v, lo, hi, err = _answer_from_stats([q], stats_row)
+        # slot s's statistics row was just written from ``seed``
+        est_v, lo, hi, err = self._row_answer(q, seed["m"], seed["ysum"],
+                                              seed["ysq"], seed["psum"])
         e = float(np.asarray(err)[0])
         decision = -1
         if q.having is not None:
-            decision = int(est.having_decision(
+            decision = est.having_verdict(
                 np.asarray(lo)[0], np.asarray(hi)[0], q.having.op,
-                q.having.threshold))
+                q.having.threshold)
         if e > q.epsilon and decision == -1:
             return False
         self._rollup_on_retire(wq, s, True)
@@ -1371,7 +1401,7 @@ class OLAWorkloadServer:
             decision=decision, plan=self.slot_plan[s],
             t_submit=wq.arrival_t, t_admit=self.slot_admit_t[s],
             t_done=self.t_model, seeded_tuples=int(self.slot_seeded[s]),
-            tuples_seen=int(np.asarray(self.state.stats.m[s]).sum()),
+            tuples_seen=int(self.slot_seeded[s]),
             rounds_resident=0, from_synopsis=True,
             sched_outcome=self._outcome(wq),
             queue_wait_model_s=self.slot_admit_t[s] - wq.arrival_t,
@@ -1394,8 +1424,9 @@ class OLAWorkloadServer:
 
     # ----------------------------------------------------------- top-up ----
     def _begin_topup_pass(self) -> bool:
-        """Re-open early-closed chunks and rewind the schedule head to the
-        first not-closed position (not all the way to 0 — fully-extracted
+        """Re-open early-closed chunks and rewind the schedule head (each
+        claim queue's head) to the first not-closed position (not all the
+        way to 0 — fully-extracted
         prefix chunks would only burn a claim round each).  Worker claims
         are dropped to IDLE so re-claiming is race-free; a re-opened chunk
         is charged as a fresh raw READ when extraction resumes past its
@@ -1414,14 +1445,13 @@ class OLAWorkloadServer:
             return False
         reopened = np.asarray(self.state.closed) & not_exhausted
         closed = np.asarray(self.state.closed) & ~not_exhausted
-        schedule = np.asarray(self.state.schedule)
-        done_sched = closed[schedule]
-        new_head = (len(schedule) if done_sched.all()
-                    else int(np.argmax(~done_sched)))
+        new_head, qhead = self.engine.program.open_heads(
+            closed, np.asarray(self.state.schedule))
         raw_touched = np.asarray(self.state.raw_touched) & ~reopened
         self.state = self.state._replace(
             closed=jnp.asarray(closed),
             head=jnp.asarray(new_head, jnp.int32),
+            qhead=jnp.asarray(qhead),
             cur=jnp.full_like(self.state.cur, IDLE),
             raw_touched=jnp.asarray(raw_touched))
         self.topup_passes += 1
@@ -1449,9 +1479,9 @@ class OLAWorkloadServer:
         for i, value, is_other in cells:
             decision = -1
             if q.having is not None and int(g_n[i]) > 0:
-                decision = int(est.having_decision(
+                decision = est.having_verdict(
                     float(g_lo[i]), float(g_hi[i]), q.having.op,
-                    q.having.threshold))
+                    q.having.threshold)
             out.append(GroupResult(
                 value=value, estimate=float(g_est[i]), lo=float(g_lo[i]),
                 hi=float(g_hi[i]), err=float(g_err[i]), n=int(g_n[i]),
@@ -1538,10 +1568,10 @@ class OLAWorkloadServer:
             bad = s in unserved or int(m_rows[s].sum()) == 0
             with self.tracer.span("ola.retire_query", qid=wq.qid, slot=s,
                                   outcome=self._outcome(wq)):
-                self._retire_slot(rep, s, wq, bad)
+                self._retire_slot(rep, s, wq, bad, int(m_rows[s].sum()))
 
-    def _retire_slot(self, rep, s: int, wq: WorkloadQuery,
-                     bad: bool) -> None:
+    def _retire_slot(self, rep, s: int, wq: WorkloadQuery, bad: bool,
+                     seen: int) -> None:
         lo_f, hi_f = float(rep.lo[s]), float(rep.hi[s])
         slo_met = None
         if wq.slo is not None:
@@ -1560,7 +1590,7 @@ class OLAWorkloadServer:
             decision=int(rep.decided[s]), plan=self.slot_plan[s],
             t_submit=wq.arrival_t, t_admit=self.slot_admit_t[s],
             t_done=self.t_model, seeded_tuples=int(self.slot_seeded[s]),
-            tuples_seen=int(np.asarray(self.state.stats.m[s]).sum()),
+            tuples_seen=seen,
             rounds_resident=int(self.rounds - self.slot_admit_round[s]),
             unserved=bad,
             sched_outcome=self._outcome(wq),

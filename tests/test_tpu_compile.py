@@ -5,7 +5,9 @@ offsets, scoped VMEM), so the parity tests cannot catch a kernel the chip's
 compiler refuses.  These tests compile each kernel for a *described* v5e
 (no chip needed) at the shapes ``chip_smoke.py`` runs: 16 ASCII columns
 (256-byte records), 16384-row chunks, 8 workers, 8 slots and the largest
-budget rung.  The topology is described inside a fixture, never at import.
+budget rung.  The whole round of the four-chip cell compiles too, sharded
+over a described v5e:2x2.  The topology is described inside a fixture,
+never at import.
 """
 
 import importlib
@@ -111,3 +113,65 @@ def test_decoded_kernel_compiles(shapes, no_compile_cache):
     s = shapes
     _compile(lambda *a: se.slot_eval_decoded_pallas(*a, num_cols=COLS),
              s["dec"], s["idx"], s["b_eff"], *s["plan"])
+
+
+def test_sharded_round_compiles_for_four_chips(topo, no_compile_cache,
+                                               monkeypatch):
+    """The whole round of ``ascii-scan-4chip`` (4,096 chunks of 16,384
+    records sharded over a v5e:2x2 ``data`` axis, 32 workers, the grouped
+    kernel) compiles, and each chip holds a quarter of the table."""
+    import numpy as np
+    from jax.sharding import AxisType, Mesh, NamedSharding
+    from jax.sharding import PartitionSpec as P
+
+    from repro.core.engine import EngineProgram, _Collectives
+    from repro.core.engine_spmd import (engine_state_specs, report_specs,
+                                        slot_table_specs)
+    from repro.core.queries import empty_slot_table
+    from repro.data.formats import AsciiFixedFormat
+    from repro.kernels import ops as kernel_ops
+
+    monkeypatch.setattr(kernel_ops, "_on_tpu", lambda: True)
+    chunks, chips, workers = 4096, 4, 32
+    mesh = Mesh(np.asarray(topo.devices[:chips]), ("data",),
+                axis_types=(AxisType.Auto,))
+    cfg = EngineConfig(num_workers=workers, budget_init=BUDGET,
+                       extract_backend="pallas", residency="sharded",
+                       max_groups=GROUPS - 1, cache_cap=64)
+    program = EngineProgram(codec=AsciiFixedFormat(COLS), config=cfg,
+                            n_chunks=chunks, m_max=CHUNK_ROWS,
+                            chunk_sizes=np.full(chunks, CHUNK_ROWS),
+                            max_slots=SLOTS, queues=chips)
+    coll = _Collectives(axis_name="data", workers_per_device=workers // chips,
+                        chunks_per_device=chunks // chips)
+
+    def step(state, table, data, speeds):
+        return program.round_body(state, data, speeds, BUDGET, coll,
+                                  slots=table)
+
+    specs = engine_state_specs()
+    fn = jax.shard_map(step, mesh=mesh,
+                       in_specs=(specs, slot_table_specs(), P("data"),
+                                 P("data")),
+                       out_specs=(specs, report_specs()), check_vma=False)
+
+    def shaped(tree, tree_specs):
+        return jax.tree.map(
+            lambda x, s: jax.ShapeDtypeStruct(
+                x.shape, x.dtype, sharding=NamedSharding(mesh, s)),
+            tree, tree_specs, is_leaf=lambda x: isinstance(x, P))
+
+    state = shaped(jax.eval_shape(program.init_state), specs)
+    table = shaped(jax.eval_shape(
+        lambda: empty_slot_table(SLOTS, COLS, GROUPS - 1)),
+        slot_table_specs())
+    data = jax.ShapeDtypeStruct((chunks, CHUNK_ROWS, REC), jnp.uint8,
+                                sharding=NamedSharding(mesh, P("data")))
+    speeds = jax.ShapeDtypeStruct((workers,), jnp.float32,
+                                  sharding=NamedSharding(mesh, P("data")))
+    compiled = jax.jit(fn).lower(state, table, data, speeds).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    quarter = chunks // chips * CHUNK_ROWS * REC
+    mem = compiled.memory_analysis()
+    assert quarter < mem.argument_size_in_bytes < quarter + 2**28
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 16e9

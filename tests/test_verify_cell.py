@@ -16,26 +16,31 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 import repro.launch.verify_cell as vc
 
 # shrink the production program for a functional run
-def small_program(budget):
+def small_program(budget, queues=1):
     from repro.core.engine import EngineConfig, EngineProgram
-    from repro.core.queries import Column, Having, Query, Range, TRUE
+    from repro.core.queries import Column, Query, Range, TRUE
     from repro.data.formats import AsciiFixedFormat
     codec = AsciiFixedFormat(6)
+    # no HAVING: a decided query stops, and once every query has stopped
+    # the round closes chunks early, so the last estimate is no census
     queries = [
-        Query(agg="avg", expr=Column(1), pred=TRUE, having=Having(">", 75.0),
-              epsilon=1e-9, name="avg_quality"),
-        Query(agg="avg", expr=Column(3), pred=TRUE, having=Having("<", 10.0),
-              epsilon=1e-9, name="avg_dup"),
-        Query(agg="count", pred=Range(0, 0.0, 16.0), having=Having("<", 1e6),
-              epsilon=1e-9, name="short_docs"),
+        Query(agg="avg", expr=Column(1), pred=TRUE, epsilon=1e-9,
+              name="avg_quality"),
+        Query(agg="avg", expr=Column(3), pred=TRUE, epsilon=1e-9,
+              name="avg_dup"),
+        Query(agg="count", pred=Range(0, 0.0, 16.0), epsilon=1e-9,
+              name="short_docs"),
     ]
     cfg = EngineConfig(num_workers=8, strategy="resource_aware",
-                       budget_init=budget, seed=0)
+                       budget_init=budget, seed=0,
+                       residency="sharded" if queues > 1 else "packed")
     sizes = np.full(16, 64, np.int64)
     return EngineProgram(codec=codec, queries=queries, config=cfg,
-                         n_chunks=16, m_max=64, chunk_sizes=sizes), cfg, codec
+                         n_chunks=16, m_max=64, chunk_sizes=sizes,
+                         queues=queues), cfg, codec
 
-vc.production_verify_program = lambda **kw: small_program(kw.get("budget", 16))
+vc.production_verify_program = lambda **kw: small_program(
+    kw.get("budget", 16), kw.get("queues", 1))
 
 mesh = jax.make_mesh((8,), ("data",))
 fn, args, program = vc.build_verify_cell(mesh, layout="sharded", budget=16)
@@ -64,6 +69,7 @@ truth_c = float(((flat[:, 0] >= 0) & (flat[:, 0] < 16)).sum())
 est = np.asarray(rep.estimate, np.float64)
 print(json.dumps({
     "exhausted": bool(rep.exhausted),
+    "census": bool((np.asarray(state.scan_m) == 64).all()),
     "est": est.tolist(),
     "truth": [truth_q, truth_d, truth_c],
     "rel_err": [abs(est[0]-truth_q)/truth_q, abs(est[1]-truth_d)/truth_d,
@@ -81,5 +87,5 @@ def test_sharded_verify_round_exact_at_exhaustion():
                          capture_output=True, text=True, timeout=900)
     assert out.returncode == 0, out.stderr[-3000:]
     res = json.loads(out.stdout.strip().splitlines()[-1])
-    assert res["exhausted"], res
+    assert res["exhausted"] and res["census"], res
     assert all(e < 5e-3 for e in res["rel_err"]), res
