@@ -1223,12 +1223,8 @@ def slot_stats_snapshot(state: EngineState, s: int) -> dict:
     never rewind).
     """
     stats = state.stats
-    return dict(
-        m=np.asarray(stats.m[s]),
-        ysum=np.asarray(stats.ysum[s]),
-        ysq=np.asarray(stats.ysq[s]),
-        psum=np.asarray(stats.psum[s]),
-    )
+    return {k: np.asarray(getattr(stats, k))[s]
+            for k in ("m", "ysum", "ysq", "psum")}
 
 
 def slot_stats_fold(state: EngineState, slot_ids) -> dict:
@@ -1261,9 +1257,9 @@ def slot_stats_write(stats: BiLevelStats, s: int, seed: Optional[dict],
                      n_chunks: int) -> tuple[BiLevelStats, int]:
     """Functional write of slot ``s``'s statistics row from a seed dict
     (synopsis seed or preemption snapshot) — zeros when ``seed`` is None.
-    Returns ``(new_stats, seeded_tuple_count)``.  Host-side, between
-    rounds; the engine round step never mutates rows of retired slots, so
-    the write is race-free by construction."""
+    Returns ``(new_stats, seeded_tuple_count)``: one indexed device update
+    a field (the workload server stages its rows on the host instead, see
+    :mod:`repro.serve.slot_writes`)."""
     dtype = stats.ysum.dtype
     if seed is None:
         m_row = jnp.zeros((n_chunks,), jnp.int32)
@@ -1283,46 +1279,14 @@ def slot_stats_write(stats: BiLevelStats, s: int, seed: Optional[dict],
         psum=stats.psum.at[s].set(ps_row)), seeded
 
 
-def zero_group_cells(state: EngineState, s: int,
-                     cells=None) -> EngineState:
-    """Zero slot ``s``'s per-group sufficient-stat rows (all cells, or the
-    given cell indices).  Host-side, between rounds — a no-op on ungrouped
-    engines.
-
-    Used at admission (a fresh occupant must not inherit the previous
-    query's cells) and by online discovery: promoting a value out of
-    ``__other__`` changes what the spill cell means, so its stats restart.
-    A restarted cell's sample is the post-restart window of each chunk's
-    committed permutation — a contiguous window of a uniform random
-    permutation, hence still a uniform without-replacement sample.
-    """
-    if state.gm.shape[1] == 0:
-        return state
-    sel = slice(None) if cells is None else np.asarray(list(cells), np.int64)
-    gm = np.asarray(state.gm).copy()
-    gys = np.asarray(state.gys).copy()
-    gyq = np.asarray(state.gyq).copy()
-    gps = np.asarray(state.gps).copy()
-    gm[s, sel] = 0
-    gys[s, sel] = 0
-    gyq[s, sel] = 0
-    gps[s, sel] = 0
-    return state._replace(gm=jnp.asarray(gm), gys=jnp.asarray(gys),
-                          gyq=jnp.asarray(gyq), gps=jnp.asarray(gps))
-
-
 def slot_group_rows(state: EngineState, s: int) -> dict:
     """Host-side copy of slot ``s``'s per-cell stat rows
     ``{gm, gys, gyq, gps}`` (each ``(G, N)``).  Per-cell counterpart of
     :func:`slot_stats_snapshot`: each cell's row has the same
     ``{m, ysum, ysq, psum}`` contract, so the rollup tier folds tracked
     cells through the exact same cell-fold path as scalar slots."""
-    return dict(
-        gm=np.asarray(state.gm[s]),
-        gys=np.asarray(state.gys[s]),
-        gyq=np.asarray(state.gyq[s]),
-        gps=np.asarray(state.gps[s]),
-    )
+    return {k: np.asarray(getattr(state, k))[s]
+            for k in ("gm", "gys", "gyq", "gps")}
 
 
 def quarantine_chunks(state: EngineState, chunk_ids) -> EngineState:

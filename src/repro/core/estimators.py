@@ -356,9 +356,10 @@ def stratified_row_answer(agg: str, sizes, n_h, m, ysum, ysq, psum,
             vx, vp = cov(ysum, ysum, ysq), cov(psum, psum, psum)
             cxp = cov(ysum, psum, ysum)
             e = tx / tp if tp != 0 else tx
-            v = max((vx + e * e * vp - 2.0 * e * cxp) / (tp * tp), 0.0)
             if tp == 0 or not np.isfinite([vx, vp, cxp]).all():
                 v = np.inf
+            else:
+                v = max((vx + e * e * vp - 2.0 * e * cxp) / (tp * tp), 0.0)
         half = (statistics.NormalDist().inv_cdf((1.0 + confidence) / 2.0)
                 * np.sqrt(max(v, 0.0)))
         lo, hi = e - half, e + half

@@ -485,8 +485,9 @@ def encode_slot(query: Query, num_cols: int, plan: str = "resource_aware",
     )
 
 
-def slot_table_set(table: SlotTable, s: int, row: dict) -> SlotTable:
-    """Functional row write (host-side, between rounds).
+def slot_row_values(table: SlotTable, row: dict) -> dict:
+    """The values of one table row, field by field (numpy, in each field's
+    dtype), from an :func:`encode_slot` row.
 
     Group columns default to the ungrouped row (``gcol=-1``, all cells dead)
     when absent or sized for a different table capacity, so rows encoded
@@ -497,46 +498,26 @@ def slot_table_set(table: SlotTable, s: int, row: dict) -> SlotTable:
     if gval_row.shape != (g,) or gact_row.shape != (g,):
         gval_row = np.zeros((g,), np.float32)
         gact_row = np.zeros((g,), np.float32)
-    return SlotTable(
-        coeffs=table.coeffs.at[s].set(jnp.asarray(row["coeffs"], jnp.float32)),
-        lo=table.lo.at[s].set(jnp.asarray(row["lo"], jnp.float32)),
-        hi=table.hi.at[s].set(jnp.asarray(row["hi"], jnp.float32)),
-        agg=table.agg.at[s].set(jnp.int32(row["agg"])),
-        plan=table.plan.at[s].set(jnp.int32(row["plan"])),
-        eps=table.eps.at[s].set(jnp.float32(row["eps"])),
-        z=table.z.at[s].set(jnp.float32(row["z"])),
-        having_op=table.having_op.at[s].set(jnp.int32(row["having_op"])),
-        having_thr=table.having_thr.at[s].set(jnp.float32(row["having_thr"])),
-        active=table.active.at[s].set(bool(row["active"])),
-        weight=table.weight.at[s].set(jnp.float32(row.get("weight", 1.0))),
-        gcol=table.gcol.at[s].set(jnp.int32(row.get("gcol", -1))),
-        gval=table.gval.at[s].set(jnp.asarray(gval_row, jnp.float32)),
-        gact=table.gact.at[s].set(jnp.asarray(gact_row, jnp.float32)),
-        gtopk=table.gtopk.at[s].set(jnp.int32(row.get("gtopk", 0))),
-    )
+    return dict(
+        coeffs=np.asarray(row["coeffs"], np.float32),
+        lo=np.asarray(row["lo"], np.float32),
+        hi=np.asarray(row["hi"], np.float32),
+        agg=np.int32(row["agg"]), plan=np.int32(row["plan"]),
+        eps=np.float32(row["eps"]), z=np.float32(row["z"]),
+        having_op=np.int32(row["having_op"]),
+        having_thr=np.float32(row["having_thr"]),
+        active=np.bool_(row["active"]),
+        weight=np.float32(row.get("weight", 1.0)),
+        gcol=np.int32(row.get("gcol", -1)), gval=gval_row, gact=gact_row,
+        gtopk=np.int32(row.get("gtopk", 0)))
 
 
-def slot_table_set_groups(table: SlotTable, s: int, gval_row, gact_row,
-                          ) -> SlotTable:
-    """Host-side group-cell write for slot ``s`` — online discovery promotes
-    sketch heavy hitters into free cells between rounds.  Only ``gval`` and
-    ``gact`` change; the rest of the row is untouched."""
-    return table._replace(
-        gval=table.gval.at[s].set(jnp.asarray(gval_row, jnp.float32)),
-        gact=table.gact.at[s].set(jnp.asarray(gact_row, jnp.float32)),
-    )
-
-
-def slot_table_clear(table: SlotTable, s: int) -> SlotTable:
-    """Deactivate a slot (query retired, deadline-enforced, or preempted);
-    descriptors are left in place so the final round's report for the slot
-    stays readable.  The fairness weight alone is reset to 1.0 — inactive
-    slots must stay neutral (the invariant ``repro.sched.fairness``
-    documents), so a weight from a contended residence never leaks into the
-    row's next occupant between the clear and the scheduler's next
-    round-weight write."""
-    return table._replace(active=table.active.at[s].set(False),
-                          weight=table.weight.at[s].set(jnp.float32(1.0)))
+def slot_table_set(table: SlotTable, s: int, row: dict) -> SlotTable:
+    """Functional row write of a device table: one indexed update a field
+    (the workload server stages its rows on the host instead)."""
+    vals = slot_row_values(table, row)
+    return SlotTable(**{f: getattr(table, f).at[s].set(vals[f])
+                        for f in SlotTable._fields})
 
 
 def slot_evaluate(table: SlotTable, cols: jnp.ndarray,

@@ -44,7 +44,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.core.controller import _answer_from_stats
 from repro.core.engine import (
     IDLE,
     EngineConfig,
@@ -52,8 +51,6 @@ from repro.core.engine import (
     slot_group_rows,
     slot_stats_fold,
     slot_stats_snapshot,
-    slot_stats_write,
-    zero_group_cells,
 )
 from repro.core.groupby import WARMUP_ROUNDS, GroupSketch, promote_values
 from repro.core.queries import (
@@ -63,9 +60,6 @@ from repro.core.queries import (
     empty_slot_table,
     encode_slot,
     group_fanout,
-    slot_table_clear,
-    slot_table_set,
-    slot_table_set_groups,
 )
 from repro.core.synopsis import BiLevelSynopsis
 from repro.core import estimators as est
@@ -83,6 +77,7 @@ from repro.sched.preempt import select_victim
 from repro.sched.scheduler import SchedulerConfig, WorkloadScheduler
 from repro.sched.slo import NO_SLO, QuerySLO
 from repro.serve.rollup import RollupConfig, RollupTier, pattern_key
+from repro.serve.slot_writes import SlotWrites
 
 
 @dataclasses.dataclass(frozen=True)
@@ -481,8 +476,6 @@ class OLAWorkloadServer:
         # config (0 keeps the group arrays zero-width — the grouped code
         # compiles away and ungrouped serving is statically unchanged)
         self.max_groups = int(self.config.max_groups)
-        self.table = empty_slot_table(max_slots, store.codec.num_cols,
-                                      self.max_groups)
         self.state = self.engine.init_state()
         self.max_slots = max_slots
         # per-slot online group discovery (grouped occupants only): the
@@ -534,7 +527,6 @@ class OLAWorkloadServer:
         # engine.quarantine_log after each round (see _note_quarantine).
         self._quarantine_seen = 0       # quarantine_log entries consumed
         self._quarantine_count = 0      # chunks quarantined so far
-        self._eff_chunks = int(store.num_chunks)
         self._eff_tuples = int(store.num_tuples)
         self._alive = np.ones(store.num_chunks, bool)
         self._eff_bytes = (float(np.asarray(store.chunk_sizes).sum())
@@ -547,6 +539,12 @@ class OLAWorkloadServer:
             set_tracer(self.tracer)
         self.metrics = metrics if metrics is not None else MetricsRegistry()
         self._bind_metrics()
+        # slot writes between two rounds stay on the host; one jitted
+        # update applies them as the next round is dispatched
+        self._writes = SlotWrites(
+            self.state, empty_slot_table(max_slots, store.codec.num_cols,
+                                         self.max_groups),
+            staged=self._slot_writes_staged, flushes=self._slot_flushes)
 
     def _bind_metrics(self) -> None:
         """Register the server's observable state on the metrics registry —
@@ -573,6 +571,13 @@ class OLAWorkloadServer:
         self._synopsis_seed_tuples = reg.counter(
             "server_synopsis_seed_tuples_total",
             help="cached tuples evaluated for synopsis seeds")
+        self._slot_writes_staged = reg.counter(
+            "server_slot_writes_staged_total",
+            help="slot row writes staged on the host between rounds")
+        self._slot_flushes = reg.counter(
+            "server_slot_flushes_total",
+            help="steps whose staged slot writes were applied before the "
+                 "round (one jitted update)")
         reg.gauge("server_slots_resident", help="occupied scan slots",
                   fn=lambda: sum(w is not None for w in self.slot_wq))
         reg.gauge("server_shed_count", help="queries shed (best-effort)",
@@ -608,6 +613,16 @@ class OLAWorkloadServer:
                           help="FaultInjector events by kind",
                           labels={"kind": kind},
                           fn=(lambda k=kind: self.store.injected.get(k, 0)))
+
+    @property
+    def table(self):
+        """The slot table as the next round will take it (a host mirror of
+        the device copy, with every write staged since the last round)."""
+        return self._writes.table
+
+    def _stopped(self) -> np.ndarray:
+        """Each slot's stop flag as the next round will take it (host)."""
+        return np.asarray(self._writes.view(self.state).stopped)
 
     def _claim_spread(self) -> int:
         qhead = np.asarray(self.state.qhead)
@@ -706,10 +721,10 @@ class OLAWorkloadServer:
                     w.explain.record_degradation(
                         round=self.rounds, t=self.t_model, chunk_ids=new)
         qn = np.asarray(self.state.quarantined)
+        self._writes.drop_chunks(qn)
         self._quarantine_count = int(qn.sum())
         sizes = np.asarray(self.store.chunk_sizes)
         alive = self._alive = ~qn
-        self._eff_chunks = int(alive.sum())
         self._eff_tuples = int(sizes[alive].sum())
         self._eff_bytes = (float(sizes[alive].sum())
                            * self.store.codec.record_bytes)
@@ -733,7 +748,7 @@ class OLAWorkloadServer:
         pre-quarantine cells may still carry their tuples)."""
         if seed is None or self._quarantine_count == 0:
             return seed
-        alive = ~np.asarray(self.state.quarantined)
+        alive = self._alive
         return dict(
             m=np.where(alive, np.asarray(seed["m"]), 0),
             ysum=np.where(alive, np.asarray(seed["ysum"]), 0.0),
@@ -804,9 +819,10 @@ class OLAWorkloadServer:
             return
         if int(np.asarray(self.state.scan_m).sum()) == 0:
             return
-        variances = self.synopsis.within_variances(self.state)
+        view = self._writes.view(self.state)
+        variances = self.synopsis.within_variances(view)
         self.synopsis.update_from_engine(
-            self.state, np.asarray(self.state.schedule), variances)
+            view, np.asarray(view.schedule), variances)
 
     def _admit_ready(self) -> None:
         if self.rollup is not None:
@@ -944,7 +960,7 @@ class OLAWorkloadServer:
         now = self.t_model
         if max(now, wq.arrival_t) + decision.predicted_service_s > deadline_t:
             return False                # hopeless even with a slot right now
-        stopped = np.asarray(self.state.stopped)
+        stopped = self._stopped()
         # grouped residents are not evictable: the eviction snapshot saves
         # only the scalar stats row, so a re-admitted grouped query would
         # silently lose its per-group cells and its discovered value set
@@ -965,7 +981,8 @@ class OLAWorkloadServer:
         (flagged ``preempted`` — it completes later, never dropped)."""
         wq = self.slot_wq[s]
         with self.tracer.span("ola.evict", qid=wq.qid, slot=s):
-            wq.saved_stats = slot_stats_snapshot(self.state, s)
+            wq.saved_stats = slot_stats_snapshot(
+                self._writes.view(self.state), s)
         wq.preempted = True
         wq.queued = True
         wq.t_enqueued = time.perf_counter()
@@ -1069,7 +1086,8 @@ class OLAWorkloadServer:
             if seed is not None:
                 promoted.fold(seed)
         if s is not None and valid:
-            self.rollup.fold(wq.key, slot_stats_snapshot(self.state, s))
+            self.rollup.fold(wq.key, slot_stats_snapshot(
+                self._writes.view(self.state), s))
 
     def _observed_mean_service_s(self) -> Optional[float]:
         """Mean scan service over completed queries; None before the first
@@ -1173,35 +1191,20 @@ class OLAWorkloadServer:
         seed = self._mask_quarantined_seed(seed)
         if seed is None or int(seed["m"].sum()) == 0:
             return 0, float("nan"), float("nan"), float("nan"), float("inf")
-        est_v, lo, hi, err = self._row_answer(
-            query, seed["m"], seed["ysum"], seed["ysq"], seed["psum"])
-        return (int(seed["m"].sum()), float(np.asarray(est_v)[0]),
-                float(np.asarray(lo)[0]), float(np.asarray(hi)[0]),
-                float(np.asarray(err)[0]))
+        return (int(seed["m"].sum()), *self._row_answer(query, seed))
 
-    def _row_answer(self, query: Query, m, ysum, ysq, psum) -> tuple:
-        """``(estimate, lo, hi, err)``, each of shape (1,), of ``query``
-        from one host statistics row, over the surviving population (after
-        quarantine the estimator's N/M are the surviving totals, the same
-        rescale the jitted round applies).  Stratified over the claim
-        queues as the round's estimates are, and then computed on the host:
-        eager ops on a mesh's replicated state would each run on every
-        device."""
-        strata = self.engine.program.queues
-        if strata > 1:
-            row = self._mask_quarantined_seed(
-                dict(m=m, ysum=ysum, ysq=ysq, psum=psum))
-            n_h = self._alive.reshape(strata, -1).sum(axis=1)
-            return tuple(np.asarray([x]) for x in est.stratified_row_answer(
-                query.agg, self.store.chunk_sizes, n_h, row["m"],
-                row["ysum"], row["ysq"], row["psum"], query.confidence))
-        dtype = self.state.stats.ysum.dtype
-        stats_row = self.state.stats._replace(
-            m=jnp.asarray(m, jnp.int32), ysum=jnp.asarray(ysum, dtype)[None],
-            ysq=jnp.asarray(ysq, dtype)[None],
-            psum=jnp.asarray(psum, dtype)[None],
-            n_total=self._eff_chunks, m_total=self._eff_tuples)
-        return _answer_from_stats([query], stats_row)
+    def _row_answer(self, query: Query, row: dict) -> tuple:
+        """``(estimate, lo, hi, err)`` of ``query`` from one statistics
+        row, over the surviving population (the quarantined chunks' columns
+        zeroed, and N the surviving count a stratum, the rescale the jitted
+        round applies).  Stratified over the claim queues as the round's
+        estimates are (one queue: one stratum, the whole table), and
+        computed on the host in float64: no device op."""
+        row = self._mask_quarantined_seed(row)
+        n_h = self._alive.reshape(self.engine.program.queues, -1).sum(axis=1)
+        return tuple(float(x) for x in est.stratified_row_answer(
+            query.agg, self.store.chunk_sizes, n_h, row["m"], row["ysum"],
+            row["ysq"], row["psum"], query.confidence))
 
     def _shed(self, wq: WorkloadQuery) -> None:
         """Answer a shed query immediately from the synopsis (flagged
@@ -1317,18 +1320,16 @@ class OLAWorkloadServer:
     def _write_slot(self, s: int, wq: WorkloadQuery, row: dict,
                     seed: Optional[dict], plan: str) -> None:
         """Give slot ``s`` to ``wq``: its statistics row from ``seed``, its
-        slot-table row, fresh group cells, and the host bookkeeping."""
-        n = self.store.num_chunks
-        stats, seeded = slot_stats_write(self.state.stats, s, seed, n)
-        self.state = self.state._replace(
-            stats=stats, stopped=self.state.stopped.at[s].set(False))
+        slot-table row and fresh group cells (staged for the next round),
+        and the host bookkeeping."""
+        seeded = self._writes.slot(s, seed)
         if self._last_err is not None:
             # the previous occupant's round-report error is stale for the
             # new one; claim weighting treats it as "no estimate yet"
             self._last_err = self._last_err.copy()
             self._last_err[s] = np.inf
-        self.table = slot_table_set(self.table, s, row)
-        # slot_table_set reset the row's fairness weight to 1.0 — keep the
+        self._writes.table_row(s, row)
+        # the row write reset the row's fairness weight to 1.0 — keep the
         # written-weights cache in sync, or _apply_scheduling could skip the
         # next write (computed vector unchanged) and leave the new occupant
         # running at full budget instead of its max-min share
@@ -1345,7 +1346,7 @@ class OLAWorkloadServer:
             # group cells start from zero for the new occupant (a prior
             # grouped resident may have left stale per-cell rows); pinned
             # values are live from the row write, the rest get discovered
-            self.state = zero_group_cells(self.state, s)
+            self._writes.restart_groups(s)
             self._slot_sketch[s] = GroupSketch(max(2 * gb.max_groups, 8))
             self._slot_groups[s] = [float(v) for v in (gb.values or ())]
         else:
@@ -1375,19 +1376,15 @@ class OLAWorkloadServer:
             # the seed meets the scalar target at best; the per-group cells
             # only fill from scan rounds, so a grouped query always scans
             return False
-        # slot s's statistics row was just written from ``seed``
-        est_v, lo, hi, err = self._row_answer(q, seed["m"], seed["ysum"],
-                                              seed["ysq"], seed["psum"])
-        e = float(np.asarray(err)[0])
+        # slot s's statistics row was just staged from ``seed``
+        est_v, lo_f, hi_f, e = self._row_answer(q, seed)
         decision = -1
         if q.having is not None:
-            decision = est.having_verdict(
-                np.asarray(lo)[0], np.asarray(hi)[0], q.having.op,
-                q.having.threshold)
+            decision = est.having_verdict(lo_f, hi_f, q.having.op,
+                                          q.having.threshold)
         if e > q.epsilon and decision == -1:
             return False
         self._rollup_on_retire(wq, s, True)
-        lo_f, hi_f = float(np.asarray(lo)[0]), float(np.asarray(hi)[0])
         slo_met = None
         if wq.slo is not None:
             slo_met = wq.slo.met(self.t_model - wq.arrival_t,
@@ -1396,8 +1393,7 @@ class OLAWorkloadServer:
             wq.explain.tier_reason = ("seed met the target at admission "
                                       "(answered without scan rounds)")
         self._finish(wq, WorkloadResult(
-            qid=wq.qid, name=q.name, estimate=float(np.asarray(est_v)[0]),
-            lo=lo_f, hi=hi_f, err=e,
+            qid=wq.qid, name=q.name, estimate=est_v, lo=lo_f, hi=hi_f, err=e,
             decision=decision, plan=self.slot_plan[s],
             t_submit=wq.arrival_t, t_admit=self.slot_admit_t[s],
             t_done=self.t_model, seeded_tuples=int(self.slot_seeded[s]),
@@ -1415,9 +1411,7 @@ class OLAWorkloadServer:
         return True
 
     def _release(self, s: int) -> None:
-        self.table = slot_table_clear(self.table, s)
-        self.state = self.state._replace(
-            stopped=self.state.stopped.at[s].set(True))
+        self._writes.release(s)
         self.slot_wq[s] = None
         self._slot_sketch[s] = None
         self._slot_groups[s] = None
@@ -1501,7 +1495,7 @@ class OLAWorkloadServer:
         tracked = self._slot_groups[s] or []
         if not tracked:
             return
-        rows = slot_group_rows(self.state, s)
+        rows = slot_group_rows(self._writes.view(self.state), s)
         base = dataclasses.replace(wq.query, group_by=None)
         for i, v in enumerate(tracked):
             fq = group_fanout(base, gb.col, [v])[0]
@@ -1523,7 +1517,7 @@ class OLAWorkloadServer:
         if self.max_groups == 0:
             return
         g_tal = None
-        stopped = np.asarray(self.state.stopped)
+        stopped = self._stopped()
         for s in range(self.max_slots):
             wq = self.slot_wq[s]
             if (wq is None or stopped[s] or wq.query.group_by is None
@@ -1550,13 +1544,13 @@ class OLAWorkloadServer:
             gact[g - 1] = 1.0   # __other__ stays live
             with self.tracer.span("ola.group_promote", qid=wq.qid, slot=s,
                                   values=len(new)):
-                self.table = slot_table_set_groups(self.table, s, gval, gact)
-                self.state = zero_group_cells(self.state, s, cells=[g - 1])
+                self._writes.groups(s, gval, gact)
+                self._writes.restart_groups(s, cells=[g - 1])
 
     # -------------------------------------------------------------- step ----
     def _retire_finished(self, rep, unserved: frozenset = frozenset()) -> None:
-        stopped = np.asarray(self.state.stopped)
-        m_rows = np.asarray(self.state.stats.m)
+        stopped = self._stopped()
+        m_rows = np.asarray(self._writes.view(self.state).stats.m)
         for s in range(self.max_slots):
             wq = self.slot_wq[s]
             if wq is None or not stopped[s]:
@@ -1627,10 +1621,10 @@ class OLAWorkloadServer:
             [wq.slo if wq is not None else None for wq in self.slot_wq],
             active)
         if not np.array_equal(w, self._cur_weights):
-            self.table = self.table._replace(
-                weight=jnp.asarray(w, jnp.float32))
+            self._writes.weights(w)
             self._cur_weights = w
-        order = sched.claim_order(self.state, self.store.chunk_sizes,
+        order = sched.claim_order(self._writes.view(self.state),
+                                  self.store.chunk_sizes,
                                   active=active,
                                   slot_need=self._slot_need())
         if order is not None:
@@ -1656,7 +1650,7 @@ class OLAWorkloadServer:
         round with the best estimate available — the OLA contract is that
         time bounds trade against accuracy, not against an answer."""
         now = self.t_model
-        stopped = np.asarray(self.state.stopped)
+        stopped = self._stopped()
         late = [s for s in range(self.max_slots)
                 if self.slot_wq[s] is not None and not stopped[s]
                 and self.slot_wq[s].slo is not None
@@ -1664,8 +1658,7 @@ class OLAWorkloadServer:
                 and now >= self.slot_wq[s].arrival_t
                 + self.slot_wq[s].slo.deadline_s]
         if late:
-            self.state = self.state._replace(
-                stopped=self.state.stopped.at[jnp.asarray(late)].set(True))
+            self._writes.stop(late)
 
     def _record_trajectory(self, rep, b) -> None:
         """Append this round's ``(m, est, ci_halfwidth, b_eff, weight)``
@@ -1731,8 +1724,9 @@ class OLAWorkloadServer:
                 self._note_quarantine()
                 mode, data = self.engine.data_mode(data)
             with tr.span("ola.dispatch", b=b, mode=mode):
+                self.state, table = self._writes.flush(self.state)
                 self.state, rep = self.engine.round_fn(b, mode)(
-                    self.state, self.table, data, self.engine.speeds)
+                    self.state, table, data, self.engine.speeds)
             self.rounds += 1
             # the report's first host read waits for the device anyway;
             # waiting here names that time
@@ -1784,12 +1778,11 @@ class OLAWorkloadServer:
         that never received a single tuple (admitted post-exhaustion with no
         synopsis seed) cannot be answered — its result is flagged
         ``unserved`` with a NaN estimate rather than a plausible-looking 0."""
-        m = np.asarray(self.state.stats.m)
+        m = np.asarray(self._writes.view(self.state).stats.m)
         unserved = frozenset(
             s for s in range(self.max_slots)
             if self.slot_wq[s] is not None and int(m[s].sum()) == 0)
-        self.state = self.state._replace(
-            stopped=jnp.ones_like(self.state.stopped))
+        self._writes.stop(range(self.max_slots))
         self._retire_finished(rep, unserved=unserved)
 
     # --------------------------------------------------------------- run ----

@@ -138,7 +138,7 @@ class RollupCell:
 
     def seed_dict(self) -> dict:
         """The cell as a ``{m, ysum, ysq, psum}`` seed row — drop-in for
-        :func:`~repro.core.engine.slot_stats_write` and the server's
+        the server's slot write (:mod:`repro.serve.slot_writes`) and its
         ``_seed_answer`` (the same contract the synopsis emits)."""
         return dict(m=self.m.copy(), ysum=self.ysum.copy(),
                     ysq=self.ysq.copy(), psum=self.psum.copy())

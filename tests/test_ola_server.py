@@ -3,12 +3,21 @@ synopsis-seeded slots."""
 
 import dataclasses
 
+import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
 from repro.core.controller import EstimationController
-from repro.core.engine import EngineConfig, OLAEngine, SlotOLAEngine
+from repro.core.engine import (
+    EngineConfig,
+    OLAEngine,
+    SlotOLAEngine,
+    quarantine_chunks,
+    slot_stats_write,
+)
 from repro.core.queries import (
+    GroupBy,
     Having,
     Linear,
     Query,
@@ -19,6 +28,7 @@ from repro.core.queries import (
 )
 from repro.core.synopsis import BiLevelSynopsis
 from repro.data.generator import make_synthetic_zipf, store_dataset
+from repro.sched import QuerySLO, SchedulerConfig, WorkloadScheduler
 from repro.serve.ola_server import (
     MeasuredRates,
     OLAWorkloadServer,
@@ -26,6 +36,7 @@ from repro.serve.ola_server import (
     load_measured_rates,
     select_plan,
 )
+from repro.serve.slot_writes import apply_slot_writes
 
 COEF = tuple(1.0 / (k + 1) for k in range(8))
 
@@ -451,3 +462,264 @@ def test_topup_pass_serves_late_tight_query(setup):
     tight = res["tight"]
     assert abs(tight.estimate - truth) / truth < 3 * 0.02
     assert tight.err <= 0.02 + 1e-6 or srv.topup_passes > 0
+
+
+# ---------------------------------------------------------------------------
+# Slot writes staged on the host, applied by one update before the round
+# ---------------------------------------------------------------------------
+
+def zero_group_cells(state, s, cells=None):
+    """Zero slot ``s``'s group cells (all, or the given indices) through a
+    host round trip: the oracle of a staged group restart."""
+    sel = slice(None) if cells is None else np.asarray(list(cells), np.int64)
+    out = {}
+    for k in ("gm", "gys", "gyq", "gps"):
+        a = np.asarray(getattr(state, k)).copy()
+        a[s, sel] = 0
+        out[k] = jnp.asarray(a)
+    return state._replace(**out)
+
+
+class EagerWrites:
+    """The oracle: every slot write an eager device op on the server's
+    state and a device table, made the moment the server asks for it."""
+
+    def __init__(self, srv):
+        self.srv = srv
+        self.table = empty_slot_table(srv.max_slots, srv.store.codec.num_cols,
+                                      srv.max_groups)
+
+    def _stopped(self, slots, value):
+        st = self.srv.state
+        self.srv.state = st._replace(
+            stopped=st.stopped.at[jnp.asarray(list(slots))].set(value))
+
+    def slot(self, s, seed):
+        stats, seeded = slot_stats_write(self.srv.state.stats, s, seed,
+                                         self.srv.store.num_chunks)
+        self.srv.state = self.srv.state._replace(stats=stats)
+        self._stopped([s], False)
+        return seeded
+
+    def stop(self, slots):
+        self._stopped(slots, True)
+
+    def restart_groups(self, s, cells=None):
+        self.srv.state = zero_group_cells(self.srv.state, s, cells)
+
+    def table_row(self, s, row):
+        self.table = slot_table_set(self.table, s, row)
+
+    def release(self, s):
+        t = self.table
+        self.table = t._replace(active=t.active.at[s].set(False),
+                                weight=t.weight.at[s].set(jnp.float32(1.0)))
+        self.stop([s])
+
+    def groups(self, s, gval, gact):
+        t = self.table
+        self.table = t._replace(
+            gval=t.gval.at[s].set(jnp.asarray(gval, jnp.float32)),
+            gact=t.gact.at[s].set(jnp.asarray(gact, jnp.float32)))
+
+    def weights(self, w):
+        self.table = self.table._replace(weight=jnp.asarray(w, jnp.float32))
+
+    def drop_chunks(self, quarantined):
+        pass                      # the quarantine write zeroed the device
+
+    def view(self, state):
+        return state
+
+    def flush(self, state):
+        return state, self.table
+
+
+def _assert_bits_equal(a, b):
+    """Every leaf of two pytrees identical: dtype, shape and bytes."""
+    la, lb = jax.tree.leaves(a), jax.tree.leaves(b)
+    assert len(la) == len(lb)
+    for x, y in zip(la, lb):
+        x, y = np.asarray(x), np.asarray(y)
+        assert x.dtype == y.dtype and x.shape == y.shape
+        assert x.tobytes() == y.tobytes()
+
+
+def _scheduled_server(store, backend, writes=None):
+    """A scheduled, grouped server with preemption on, recording the state
+    and table every round takes; ``writes`` swaps its slot writes."""
+    cfg = EngineConfig(num_workers=2, seed=5, max_groups=4,
+                       extract_backend=backend)
+    srv = OLAWorkloadServer(store, cfg, options=ServerOptions(
+        max_slots=3, synopsis_budget_tuples=512, group_warmup_tuples=64,
+        scheduler=WorkloadScheduler(SchedulerConfig(
+            preempt=True, queue_policy="fifo", shed_enabled=False))))
+    if writes is not None:
+        srv._writes = writes(srv)
+    taken = []
+    orig = srv.engine.round_fn
+
+    def round_fn(b, mode="none"):
+        fn = orig(b, mode)
+
+        def run(state, table, data, speeds):
+            taken.append(jax.tree.map(np.array, (state, table)))
+            return fn(state, table, data, speeds)
+        return run
+
+    srv.engine.round_fn = round_fn
+    t = store.num_tuples / srv._scan_rate
+    # two admissions in the first step, one of them grouped (it promotes)
+    srv.submit(Query(agg="sum", expr=Linear(COEF), epsilon=1e-6, name="a"),
+               arrival_t=0.0, slo=QuerySLO(priority="batch"))
+    srv.submit(Query(agg="sum", expr=Linear((0.0, 1.0) + (0.0,) * 6),
+                     epsilon=0.01, name="g",
+                     group_by=GroupBy(col=7, max_groups=4, top_k=2)),
+               arrival_t=0.0)
+    # v takes the free slot from the synopsis, and p evicts it in the
+    # same step: the snapshot reads v's row before any round wrote it
+    srv.submit(Query(agg="sum", expr=Linear(COEF), epsilon=0.03, name="v"),
+               arrival_t=0.1 * t, slo=QuerySLO(priority="batch"))
+    srv.submit(Query(agg="sum", expr=Linear(COEF), epsilon=0.08, name="p"),
+               arrival_t=0.1 * t,
+               slo=QuerySLO(deadline_s=0.3 * t, priority="interactive"))
+    # d is stopped at its deadline; s retires from its seed at admission
+    srv.submit(Query(agg="count", pred=Range(1, 0.0, 5e7), epsilon=1e-9,
+                     name="d"),
+               arrival_t=0.5 * t, slo=QuerySLO(deadline_s=0.05 * t))
+    srv.submit(Query(agg="count", pred=Range(0, 0.0, 9e7), epsilon=0.5,
+                     name="s"), arrival_t=0.7 * t)
+    return srv, taken
+
+
+@pytest.mark.parametrize("backend", ["ref", "pallas-interpret"])
+def test_staged_slot_writes_match_eager_writes(setup, backend):
+    """Staged writes and one update before each round leave the state and
+    table every round takes bit-identical to eager writes, through seed
+    retirement, several admissions in a step, a grouped admission and a
+    promotion, releases, a deadline stop, the census, and the preemption
+    snapshot of a row staged in the same step."""
+    vals, store = setup
+    staged, staged_taken = _scheduled_server(store, backend)
+    eager, eager_taken = _scheduled_server(store, backend, EagerWrites)
+    evicted, promoted = [], []
+    evict, groups = staged._evict, staged._writes.groups
+
+    def evict_staged(s):
+        evicted.append(bool(staged._writes._rows.row[s]))
+        evict(s)
+
+    def groups_counted(s, gval, gact):
+        promoted.append(s)
+        groups(s, gval, gact)
+
+    staged._evict, staged._writes.groups = evict_staged, groups_counted
+    for _ in range(200):
+        busy = staged.step()
+        assert eager.step() == busy
+        # the state and table the next round will take
+        _assert_bits_equal(staged._writes.view(staged.state), eager.state)
+        _assert_bits_equal(staged.table, eager.table)
+        if not busy and not staged.queue:
+            break
+    assert len(staged_taken) == len(eager_taken) == staged.rounds > 0
+    for a, b in zip(staged_taken, eager_taken):
+        _assert_bits_equal(a, b)
+    # (repr: the __other__ cell's value is NaN; float reprs are exact)
+    assert repr(staged.results) == repr(eager.results)
+    res = {r.name: r for r in staged.results}
+    assert res["s"].from_synopsis and res["s"].rounds_resident == 0
+    assert evicted == [True] and res["v"].sched_outcome == "preempted"
+    assert res["d"].err > 1e-9 and res["d"].slo_met is False
+    assert promoted and any(not c.is_other for c in res["g"].groups)
+    staged.close()
+    eager.close()
+
+
+def test_quarantine_in_the_step_reaches_staged_rows(setup):
+    """A chunk quarantined by a failed read inside the step that admitted a
+    seeded query (after the row was staged, before the flush) leaves the
+    slot's row as the eager write followed by the quarantine write did:
+    bit-identical, the chunk's columns zero."""
+    vals, store = setup
+    servers = []
+    for writes in (None, EagerWrites):
+        srv = OLAWorkloadServer(store, EngineConfig(num_workers=2, seed=5),
+                                options=ServerOptions(
+                                    max_slots=3, synopsis_budget_tuples=512))
+        if writes is not None:
+            srv._writes = writes(srv)
+        orig = srv.engine.round_data
+
+        def round_data(state, srv=srv, orig=orig):
+            # the read of the first scanned chunk fails as "late" joins
+            if not srv.engine.quarantine_log and any(
+                    w is not None and w.query.name == "late"
+                    for w in srv.slot_wq):
+                j = int(np.asarray(state.schedule)[0])
+                state = quarantine_chunks(state, [j])
+                srv.engine.quarantine_log.append(j)
+            return orig(state)
+
+        srv.engine.round_data = round_data
+        srv.submit(Query(agg="sum", expr=Linear(COEF), epsilon=1e-6,
+                         name="first"), arrival_t=0.0)
+        for _ in range(6):
+            srv.step()
+        srv.submit(Query(agg="count", pred=Range(0, 0.0, 9e7),
+                         epsilon=1e-6, name="late"), arrival_t=srv.t_model)
+        srv.step()
+        servers.append(srv)
+    staged, eager = servers
+    (j,) = staged.engine.quarantine_log
+    late = next(s for s, w in enumerate(staged.slot_wq)
+                if w is not None and w.query.name == "late")
+    assert staged.slot_seeded[late] > 0
+    assert int(np.asarray(staged.state.stats.m)[late, j]) == 0
+    _assert_bits_equal(staged.state, eager.state)
+    for srv in servers:
+        srv.close()
+
+
+def test_slot_write_counters(setup):
+    """One flush applies a step's several admissions; a step that admits
+    and releases nothing flushes nothing."""
+    vals, store = setup
+    srv = OLAWorkloadServer(store, EngineConfig(num_workers=2, seed=5),
+                            options=ServerOptions(max_slots=3))
+
+    def counts():
+        snap = srv.metrics_snapshot()
+        return (snap["server_slot_flushes_total"],
+                snap["server_slot_writes_staged_total"])
+
+    assert counts() == (0, 0)
+    for name in ("a", "b", "c"):
+        srv.submit(Query(agg="sum", expr=Linear(COEF), epsilon=1e-6,
+                         name=name), arrival_t=0.0)
+    srv.step()
+    flushes, staged = counts()
+    assert flushes == 1 and staged >= 3
+    srv.step()
+    assert counts() == (flushes, staged)
+    srv.run()
+    flushes, staged = counts()
+    assert staged >= flushes >= 1
+    srv.close()
+
+
+def test_slot_write_update_compiles_once_per_shape():
+    """Servers built one after the other, as a benchmark builds one a pass,
+    share the one compiled update of their shape."""
+    store = store_dataset(make_synthetic_zipf(1536, 8, seed=11), 24, "ascii")
+    apply_slot_writes.clear_cache()
+    for _ in range(2):
+        srv = OLAWorkloadServer(store, EngineConfig(num_workers=2, seed=5),
+                                options=ServerOptions(max_slots=7))
+        for name in ("a", "b"):
+            srv.submit(Query(agg="sum", expr=Linear(COEF), epsilon=0.05,
+                             name=name), arrival_t=0.0)
+        srv.run()
+        assert srv.metrics_snapshot()["server_slot_flushes_total"] >= 1
+        srv.close()
+    assert apply_slot_writes._cache_size() == 1

@@ -203,8 +203,9 @@ def test_hot_repeat_answered_from_rollup_without_scan_rounds(setup):
 
 def test_fully_covered_cell_matches_fresh_census(setup):
     """A cell whose every chunk is fully extracted answers *exactly*: the
-    FPC zeroes all variance, and the estimate matches a fresh full scan of
-    the same query bit for bit."""
+    FPC zeroes all variance, the cell holds a fresh full scan's statistics,
+    and the estimate matches that scan's census row answered the way a
+    cached row is (on the host, in float64) bit for bit."""
     vals, store = setup
     q_census = lambda name: _hot(name, epsilon=1e-9)   # forces a census
     cfg = EngineConfig(num_workers=2, seed=5)
@@ -231,7 +232,12 @@ def test_fully_covered_cell_matches_fresh_census(setup):
                 options=ServerOptions(max_slots=4, synopsis_budget_tuples=0))
     fresh.submit(q_census("ref"), arrival_t=0.0)
     (ref,) = fresh.run()
-    assert r2.estimate == ref.estimate        # bit-identical, not just close
+    row = slot_stats_snapshot(fresh.state, 0)
+    for k in ("m", "ysum", "ysq", "psum"):
+        np.testing.assert_array_equal(getattr(cell, k), row[k])
+    _, census, *_ = fresh._seed_answer(q_census("ref"), seed=row)
+    assert r2.estimate == census              # bit-identical, not just close
+    np.testing.assert_allclose(ref.estimate, census, rtol=1e-6)
     np.testing.assert_allclose(r2.estimate, _truth(vals), rtol=1e-5)
     srv.close()
     fresh.close()

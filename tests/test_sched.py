@@ -572,7 +572,7 @@ def test_fairness_weights_survive_slot_churn(setup):
     w = np.asarray(srv.table.weight)
     assert w[0] == pytest.approx(0.5)       # a's contended fair share
     # b (a loose count) may retire within this very step; its cleared row
-    # then resets to the neutral 1.0 (slot_table_clear keeps inactive slots
+    # then resets to the neutral 1.0 (a release keeps inactive slots
     # neutral so no contended weight leaks to the next occupant)
     assert w[1] == pytest.approx(1.0 if srv.slot_wq[1] is None else 0.5)
     # b retires fast (loose count); c takes its slot — same computed vector
